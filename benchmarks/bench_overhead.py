@@ -5,7 +5,7 @@ Four views:
     tables vs a plain mean over stacked peer gradients, as d grows, for both
     the pure-jnp pipeline and the fused Pallas kernel (interpret mode on
     CPU — the interpreter is slow, so the *pass model* is the bandwidth
-    signal there; on a TPU set REPRO_PALLAS_COMPILE=1);
+    signal there; on a TPU the kernels compile natively);
   * the HBM-pass model: the seed kernel family streamed the (n, d) peer
     stack 2*n_iters + 1 times per aggregation (norm phase + update phase per
     clip iteration, then a standalone table pass); the fused kernel's
@@ -806,9 +806,8 @@ def main(fast=True, out_dir=None):
     payload = {
         "bench": "overhead",
         "backend": jax.default_backend(),
-        "pallas_mode": "interpret"
-        if os.environ.get("REPRO_PALLAS_COMPILE", "0") != "1"
-        else "compiled",
+        "pallas_mode": "compiled" if jax.default_backend() == "tpu"
+        else "interpret",
         "comm_per_spec": {"n_peers": n, "d": dims[-1], "specs": comm_per_spec},
         "flat_cost_scaling": scaling,
         "symbolic_comm": symbolic,

@@ -11,6 +11,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import InputShape
 from repro.data import TokenPipeline
+from repro.launch.mesh import make_mesh
 from repro.launch.steps import make_decode_step, make_prefill_step
 from repro.models import get_model
 from repro.sharding import set_mesh
@@ -24,7 +25,7 @@ def main():
     ap.add_argument("--gen", type=int, default=12)
     args = ap.parse_args()
 
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     set_mesh(mesh)
     m = get_model(args.arch, reduced=True)
     total = args.prompt_len + args.gen

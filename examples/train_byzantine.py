@@ -154,6 +154,9 @@ def run_toy(args):
 
 def main():
     args = build_parser().parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     if args.model:
         run_model(args)
     else:

@@ -173,6 +173,8 @@ def apply_attack(idx, grads, byz_mask, *, key, lam=1000.0, delayed=None,
     if delayed is None:
         delayed = jnp.zeros_like(grads)
     lam = jnp.asarray(lam, grads.dtype)
+    if isinstance(idx, int):  # static choice: no switch, no unused operands
+        return _REGISTRY[idx](grads, byz_mask, key, lam, delayed, hon_mask)
     return jax.lax.switch(
         jnp.asarray(idx, jnp.int32),
         _REGISTRY,

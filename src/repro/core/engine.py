@@ -113,7 +113,8 @@ class ProtocolState(NamedTuple):
     col_checked: jnp.ndarray  # (n,) i32 — step each digest COLUMN was last
     # broadcast/audited (sampled-digest mode's staleness ledger; all
     # columns every step when sampling is off)
-    delay_buf: jnp.ndarray  # (D, n, d) f32 — ring buffer for delayed attack
+    delay_buf: jnp.ndarray  # (D, n, d) — ring buffer for delayed attack
+    # (D = cfg.delay_depth: 0 rows unless the attack is delayed_gradient)
     # --- elastic membership (core.sybil) ---
     lifecycle: jnp.ndarray  # (n,) i32 — SLOT_* code per slot
     slot_identity: jnp.ndarray  # (n,) i32 — identity occupying each slot
@@ -262,7 +263,9 @@ class EngineConfig:
 
     @property
     def delay_depth(self) -> int:
-        return max(1, self.delay) if self.attack == "delayed_gradient" else 1
+        """Rows of gradient history the state carries: the delayed_gradient
+        attack's delay, and 0 for every other run."""
+        return max(1, self.delay) if self.attack == "delayed_gradient" else 0
 
 
 def config_from_attack(n, d, attack, **kw) -> EngineConfig:
@@ -335,11 +338,15 @@ def init_state(cfg: EngineConfig, seed: int = 0, events=None,
     array). ``vacant``: slots that start unoccupied (capacity reclaimed by
     later join events)."""
     n = cfg.n
-    buf_elems = cfg.delay_depth * n * cfg.d
-    if buf_elems > 2**28:  # > ~0.5 GiB of bf16 carried through every step
+    # bf16: the buffer only feeds the delayed ATTACK rows (they mismatch
+    # honest_G regardless), and it is the one O(delay·n·d) carry; every
+    # other attack carries it empty
+    buf_dtype = jnp.bfloat16 if cfg.delay_depth > 1 else jnp.float32
+    buf_bytes = cfg.delay_depth * n * cfg.d * jnp.dtype(buf_dtype).itemsize
+    if buf_bytes > 2**29:  # > 0.5 GiB carried through every step
         raise ValueError(
             f"delayed_gradient ring buffer would be (delay={cfg.delay}, "
-            f"n={n}, d={cfg.d}) = {2 * buf_elems / 2**30:.1f} GiB of scan "
+            f"n={n}, d={cfg.d}) = {buf_bytes / 2**30:.1f} GiB of scan "
             "carry; set AttackConfig.delay to the actual delay you want "
             "(typical runs use 5-50 — the legacy host buffer grew lazily, "
             "the engine's is dense)"
@@ -376,12 +383,7 @@ def init_state(cfg: EngineConfig, seed: int = 0, events=None,
         accused_count=jnp.zeros((n,), jnp.int32),
         last_checked=jnp.full((n,), -1, jnp.int32),
         col_checked=jnp.full((n,), -1, jnp.int32),
-        # bf16: the buffer only feeds the delayed ATTACK rows (they mismatch
-        # honest_G regardless), and it is the one O(delay·n·d) carry
-        delay_buf=jnp.zeros(
-            (cfg.delay_depth, n, cfg.d),
-            jnp.bfloat16 if cfg.delay_depth > 1 else jnp.float32,
-        ),
+        delay_buf=jnp.zeros((cfg.delay_depth, n, cfg.d), buf_dtype),
         lifecycle=lifecycle,
         slot_identity=slot_identity,
         probation_clean=jnp.zeros((n,), jnp.int32),
@@ -500,9 +502,10 @@ def phase_attack(cfg: EngineConfig, state: ProtocolState, G, honest_G, byz,
     delay_buf = state.delay_buf
 
     if cfg.has_gradient_attack:
-        slot = t % cfg.delay_depth
-        # written at t - delay_depth (zeros before)
-        delayed = delay_buf[slot].astype(jnp.float32)
+        delayed = None
+        if cfg.delay_depth:
+            # written at t - delay_depth (zeros before)
+            delayed = delay_buf[t % cfg.delay_depth].astype(jnp.float32)
         G = attacks_mod.apply_attack(
             attacks_mod.attack_index(cfg.attack),
             G,
@@ -513,7 +516,7 @@ def phase_attack(cfg: EngineConfig, state: ProtocolState, G, honest_G, byz,
             hon_mask=~byz & active_b,
         )
     # history for the delayed attack (honest rows of byzantine peers)
-    if cfg.attack == "delayed_gradient":
+    if cfg.delay_depth:
         slot = t % cfg.delay_depth
         delay_buf = delay_buf.at[slot].set(
             jnp.where((byz & active_b)[:, None], honest_G, 0.0).astype(

@@ -36,16 +36,16 @@ time per clip iteration — see DESIGN.md for the full derivation:
   sort (trimmed mean, coordinate median — nothing to fuse into).
 
 * ``_md_kernel`` / ``mean_digest_fused_pallas`` — verified:mean's fused
-  aggregation + digest epilogue: the weighted per-partition mean is a
-  single streaming reduction, so the digest tables ride the same
-  pallas_call (2 HBM passes of x total, zero materialized temporaries) —
-  the fused-epilogue treatment the ButterflyClip flagship already gets.
+  aggregation + digests: the weighted per-partition mean decomposes over
+  lanes, so each block's aggregate is final as soon as it is computed and
+  the digest dot / squared norm accumulate against it in the same grid
+  step (1 HBM pass of x, zero materialized temporaries).
 
 * dequant variants (``butterfly_clip_fused_dequant_pallas``,
   ``mean_digest_fused_dequant_pallas``) — the same fused bodies over WIRE
   payloads (core.compression): xs stays int8/bf16 in HBM for every pass
   and is dequantized in-register against a per-(partition, peer) f32
-  sidecar scale, so ``compressed:*`` specs keep the n_iters + 2 (resp. 2)
+  sidecar scale, so ``compressed:*`` specs keep the n_iters + 2 (resp. 1)
   pass structure over 1-2 byte data — ≈4× (int8) fewer HBM bytes per pass.
   All arithmetic runs on the dequantized f32 values (the same bits the jnp
   path computes), which is what keeps compressed verification exact.
@@ -54,7 +54,16 @@ Block geometry: peers stay un-tiled (n <= ~64 on the peer axis), the
 partition dim is tiled by ``block`` (lane-aligned multiples of 128). Inputs
 are zero-padded to a block multiple — zero columns where x == v == z == 0
 contribute nothing to norms, dots, or updates, so padding is exact.
-Validated on CPU with interpret=True against kernels/ref.py.
+
+Multi-pass kernels carry the iterate v through HBM: v is an ``pl.ANY``
+output aliased to the v0 input, and each grid step copies its (1, blk)
+window in and, after an update, back out (``_hbm_window``). A pipelined
+VMEM output block cannot carry it, because the TPU pipeline only ever
+writes an output block back and never re-reads it: a block revisited in a
+later pass would start from whatever the VMEM buffer last held.
+
+Every kernel compiles natively on a TPU and runs in the Pallas interpreter
+on the CPU (``_pallas_call``), where it is validated against kernels/ref.py.
 """
 from __future__ import annotations
 
@@ -68,34 +77,58 @@ from jax.experimental.pallas import tpu as pltpu
 DEFAULT_BLOCK = 512
 
 
+def _pallas_call(kernel, *, interpret=None, **kwargs):
+    """``pl.pallas_call`` that runs natively on a TPU and in the Pallas
+    interpreter on the CPU. The platform is the one the call is lowered
+    for (``lax.platform_dependent``), read then and not at import; lowering
+    for any other platform raises. ``interpret=True``/``False`` forces one
+    mode (the native-lowering tests compile ``False`` for a described TPU).
+    """
+    if interpret is not None:
+        return pl.pallas_call(kernel, interpret=interpret, **kwargs)
+    native = pl.pallas_call(kernel, interpret=False, **kwargs)
+    interp = pl.pallas_call(kernel, interpret=True, **kwargs)
+    return lambda *args: jax.lax.platform_dependent(
+        *args, cpu=interp, tpu=native
+    )
+
+
+def _hbm_window(ref, blk, width, part=None):
+    """The (1, width) lane window ``blk`` of the HBM iterate ``ref`` ((1, dp),
+    or (n_parts, 1, dp) with ``part``), for ``pltpu.sync_copy``."""
+    lanes = pl.ds(pl.multiple_of(blk * width, width), width)
+    return ref.at[:, lanes] if part is None else ref.at[part, :, lanes]
+
+
+_ANY = pl.BlockSpec(memory_space=pl.ANY)  # an HBM ref the kernel copies by hand
+
+
 # ===========================================================================
 # CenteredClip fixed-point kernel
 # ===========================================================================
-def _cc_kernel(taus_ref, w_ref, xs_ref, v_ref, out_ref, sq_ref, cw_ref):
+def _cc_kernel(taus_ref, w_ref, xs_ref, v0_ref, out_ref, sq_ref, cw_ref,
+               v_ref):
     """Grid (n_iters, 2, n_blocks).
 
     taus: (n_iters, 1) in SMEM (whole schedule, indexed by the pass id —
     a (1, 1) VMEM block would violate the TPU (8, 128) tile minimum);
-    w: (n, 1) peer weights; xs: (n, blk) tile; v/out: (1, blk) aliased;
-    scratch sq/cw: (n, 1) f32.
+    w: (n, 1) peer weights; xs: (n, blk) tile; out: the (1, dp) HBM
+    iterate, aliased to v0 (so it starts as v0); scratch sq/cw: (n, 1) f32,
+    v: this step's (1, blk) window of the iterate.
     """
     it = pl.program_id(0)
     phase = pl.program_id(1)
     blk = pl.program_id(2)
+    v_hbm = _hbm_window(out_ref, blk, v_ref.shape[-1])
+    pltpu.sync_copy(v_hbm, v_ref)
 
     @pl.when(phase == 0)
     def _phase_norms():
-        @pl.when(it == 0)
-        def _copy_in():
-            # v lives in out_ref from here on (aliasing the input ref is not
-            # readable-after-write in interpret mode)
-            out_ref[...] = v_ref[...]
-
         @pl.when(blk == 0)
         def _reset():
             sq_ref[...] = jnp.zeros_like(sq_ref)
 
-        diff = xs_ref[...].astype(jnp.float32) - out_ref[...].astype(jnp.float32)
+        diff = xs_ref[...].astype(jnp.float32) - v_ref[...]
         sq_ref[...] += jnp.sum(diff * diff, axis=1, keepdims=True)
 
     @pl.when(phase == 1)
@@ -109,14 +142,15 @@ def _cc_kernel(taus_ref, w_ref, xs_ref, v_ref, out_ref, sq_ref, cw_ref):
             cw_ref[...] = cw * w_ref[...].astype(jnp.float32)
 
         wsum = jnp.maximum(jnp.sum(w_ref[...].astype(jnp.float32)), 1e-30)
-        diff = xs_ref[...].astype(jnp.float32) - out_ref[...].astype(jnp.float32)
+        diff = xs_ref[...].astype(jnp.float32) - v_ref[...]
         upd = jnp.sum(cw_ref[...] * diff, axis=0, keepdims=True) / wsum
-        out_ref[...] = out_ref[...] + upd
+        v_ref[...] = v_ref[...] + upd
+        pltpu.sync_copy(v_ref, v_hbm)
 
 
 def centered_clip_pallas(
     xs, taus, weights=None, v0=None, *,
-    block: int = DEFAULT_BLOCK, interpret: bool = True,
+    block: int = DEFAULT_BLOCK, interpret: bool | None = None,
 ):
     """CenteredClip via the Pallas kernel. xs: (n, d) -> v: (d,) f32.
 
@@ -143,21 +177,23 @@ def centered_clip_pallas(
         else v0.reshape(1, dp).astype(jnp.float32)
     )
 
-    out = pl.pallas_call(
+    out = _pallas_call(
         _cc_kernel,
         grid=(n_iters, 2, n_blocks),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((n, 1), lambda i, p, b: (0, 0)),
             pl.BlockSpec((n, blk), lambda i, p, b: (0, b)),
-            pl.BlockSpec((1, blk), lambda i, p, b: (0, b)),
+            _ANY,
         ],
-        out_specs=pl.BlockSpec((1, blk), lambda i, p, b: (0, b)),
+        out_specs=_ANY,
         out_shape=jax.ShapeDtypeStruct((1, dp), jnp.float32),
         scratch_shapes=[
             pltpu.VMEM((n, 1), jnp.float32),
             pltpu.VMEM((n, 1), jnp.float32),
+            pltpu.VMEM((1, blk), jnp.float32),
         ],
+        input_output_aliases={3: 0},
         interpret=interpret,
     )(taus2, w2, xs, v0)
     return out[0, :d]
@@ -169,27 +205,24 @@ def centered_clip_pallas(
 # index is outermost so the per-peer scratch naturally re-initializes at
 # each partition's first grid step)
 # ===========================================================================
-def _bcc_kernel(taus_ref, w_ref, xs_ref, v_ref, out_ref, sq_ref, cw_ref):
-    """Like _cc_kernel with a leading partition grid axis. v/out carry a
-    singleton sublane dim — (n_parts, 1, dp) with (1, 1, blk) blocks — so
-    the native TPU lowering sees a legal (1, blk) tile instead of a (1, blk)
-    slice of a (n_parts, dp) array (sublane dim must divide 8 or equal the
-    array dim)."""
+def _bcc_kernel(taus_ref, w_ref, xs_ref, v0_ref, out_ref, sq_ref, cw_ref,
+                v_ref):
+    """Like _cc_kernel with a leading partition grid axis. The iterate
+    carries a singleton sublane dim — (n_parts, 1, dp) — so each step's
+    window is a (1, blk) row like the unbatched kernel's."""
     it = pl.program_id(1)
     phase = pl.program_id(2)
     blk = pl.program_id(3)
+    v_hbm = _hbm_window(out_ref, blk, v_ref.shape[-1], pl.program_id(0))
+    pltpu.sync_copy(v_hbm, v_ref)
 
     @pl.when(phase == 0)
     def _phase_norms():
-        @pl.when(it == 0)
-        def _copy_in():
-            out_ref[0] = v_ref[0]
-
         @pl.when(blk == 0)
         def _reset():
             sq_ref[...] = jnp.zeros_like(sq_ref)
 
-        diff = xs_ref[0].astype(jnp.float32) - out_ref[0].astype(jnp.float32)
+        diff = xs_ref[0].astype(jnp.float32) - v_ref[...]
         sq_ref[...] += jnp.sum(diff * diff, axis=1, keepdims=True)
 
     @pl.when(phase == 1)
@@ -203,14 +236,15 @@ def _bcc_kernel(taus_ref, w_ref, xs_ref, v_ref, out_ref, sq_ref, cw_ref):
             cw_ref[...] = cw * w_ref[...].astype(jnp.float32)
 
         wsum = jnp.maximum(jnp.sum(w_ref[...].astype(jnp.float32)), 1e-30)
-        diff = xs_ref[0].astype(jnp.float32) - out_ref[0].astype(jnp.float32)
+        diff = xs_ref[0].astype(jnp.float32) - v_ref[...]
         upd = jnp.sum(cw_ref[...] * diff, axis=0, keepdims=True) / wsum
-        out_ref[0] = out_ref[0] + upd
+        v_ref[...] = v_ref[...] + upd
+        pltpu.sync_copy(v_ref, v_hbm)
 
 
 def butterfly_clip_pallas(
     parts, taus, weights=None, v0=None, *,
-    block: int = DEFAULT_BLOCK, interpret: bool = True,
+    block: int = DEFAULT_BLOCK, interpret: bool | None = None,
 ):
     """All-partition CenteredClip: parts (n_parts, n_peers, part) -> the
     robust aggregate (n_parts, part) f32 — i.e. ButterflyClip's aggregation
@@ -235,21 +269,23 @@ def butterfly_clip_pallas(
         else v0.astype(jnp.float32).reshape(n_parts, 1, dp)
     )
 
-    out = pl.pallas_call(
+    out = _pallas_call(
         _bcc_kernel,
         grid=(n_parts, n_iters, 2, n_blocks),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((n, 1), lambda p, i, ph, b: (0, 0)),
             pl.BlockSpec((1, n, blk), lambda p, i, ph, b: (p, 0, b)),
-            pl.BlockSpec((1, 1, blk), lambda p, i, ph, b: (p, 0, b)),
+            _ANY,
         ],
-        out_specs=pl.BlockSpec((1, 1, blk), lambda p, i, ph, b: (p, 0, b)),
+        out_specs=_ANY,
         out_shape=jax.ShapeDtypeStruct((n_parts, 1, dp), jnp.float32),
         scratch_shapes=[
             pltpu.VMEM((n, 1), jnp.float32),
             pltpu.VMEM((n, 1), jnp.float32),
+            pltpu.VMEM((1, blk), jnp.float32),
         ],
+        input_output_aliases={3: 0},
         interpret=interpret,
     )(taus2, w2, parts, v0)
     return out[:, 0, :d]
@@ -260,7 +296,8 @@ def butterfly_clip_pallas(
 # verification epilogue. Grid (n_iters + 2, n_blocks) (a leading n_parts
 # axis in the batched variant):
 #
-#   pass 0            prologue: v := v0, sq_i := ||x_i - v0||^2
+#   pass 0            prologue: sq_i := ||x_i - v0||^2 (the HBM iterate
+#                     starts as v0: it is aliased to the v0 input)
 #   pass 1..n_iters   at blk 0 convert sq -> clip weights, zero sq; then per
 #                     block: upd = sum_i cw_i (x_i - v) / wsum, v += upd, and
 #                     sq_i += ||diff_i - upd||^2 — the NEXT iteration's
@@ -274,12 +311,14 @@ def butterfly_clip_pallas(
 # two-phase kernel plus the standalone table kernel.
 # ===========================================================================
 def _fused_body(
-    batched, taus_ref, tauv_ref, w_ref, xs_ref, v_ref, z_ref,
-    out_ref, s_ref, norm_ref, sq_ref, cw_ref, dot_ref, *, scales_ref=None,
+    batched, taus_ref, tauv_ref, w_ref, xs_ref, v0_ref, z_ref,
+    out_ref, s_ref, norm_ref, sq_ref, cw_ref, dot_ref, v_ref, *,
+    scales_ref=None,
 ):
     """taus/tauv live in SMEM (whole schedule, indexed by the pass id); in
-    the batched variant v/z/out/s/norm carry a singleton sublane dim (see
-    _bcc_kernel) so every VMEM block satisfies the TPU tiling rules.
+    the batched variant z/out/s/norm carry a singleton sublane dim (see
+    _bcc_kernel) so every VMEM block satisfies the TPU tiling rules. out is
+    the HBM iterate (aliased to v0); v_ref holds this step's window of it.
 
     scales_ref (dequant variant): per-peer f32 sidecar scales — xs arrives
     in its WIRE dtype (int8 / bf16) and is dequantized in-register
@@ -295,24 +334,18 @@ def _fused_body(
     xs = (xs_ref[0] if batched else xs_ref[...]).astype(jnp.float32)
     if scales_ref is not None:  # in-register dequantize of the wire payload
         xs = xs * (scales_ref[0] if batched else scales_ref[...])
-    # 2D (1, blk) views of the possibly 3D-blocked refs
-    vget = (lambda r: r[0]) if batched else (lambda r: r[...])
-
-    def out_set(val):
-        if batched:
-            out_ref[0] = val
-        else:
-            out_ref[...] = val
+    z = (z_ref[0] if batched else z_ref[...]).astype(jnp.float32)
+    v_hbm = _hbm_window(out_ref, blk, v_ref.shape[-1],
+                        pl.program_id(0) if batched else None)
+    pltpu.sync_copy(v_hbm, v_ref)
 
     @pl.when(it == 0)
     def _prologue():
-        out_set(vget(v_ref).astype(jnp.float32))
-
         @pl.when(blk == 0)
         def _reset():
             sq_ref[...] = jnp.zeros_like(sq_ref)
 
-        diff = xs - vget(out_ref)
+        diff = xs - v_ref[...]
         sq_ref[...] += jnp.sum(diff * diff, axis=1, keepdims=True)
 
     @pl.when(jnp.logical_and(it >= 1, it <= n_upd))
@@ -327,9 +360,10 @@ def _fused_body(
             sq_ref[...] = jnp.zeros_like(sq_ref)  # accumulates iter l+1 norms
 
         wsum = jnp.maximum(jnp.sum(w_ref[...].astype(jnp.float32)), 1e-30)
-        diff = xs - vget(out_ref)
+        diff = xs - v_ref[...]
         upd = jnp.sum(cw_ref[...] * diff, axis=0, keepdims=True) / wsum
-        out_set(vget(out_ref) + upd)
+        v_ref[...] = v_ref[...] + upd
+        pltpu.sync_copy(v_ref, v_hbm)
         nd = diff - upd  # x_i - v_{l+1} restricted to this block
         sq_ref[...] += jnp.sum(nd * nd, axis=1, keepdims=True)
 
@@ -339,9 +373,8 @@ def _fused_body(
         def _reset_dot():
             dot_ref[...] = jnp.zeros_like(dot_ref)
 
-        diff = xs - vget(out_ref)
-        dot_ref[...] += jnp.sum(diff * vget(z_ref).astype(jnp.float32),
-                                axis=1, keepdims=True)
+        diff = xs - v_ref[...]
+        dot_ref[...] += jnp.sum(diff * z, axis=1, keepdims=True)
 
         @pl.when(blk == nb - 1)
         def _tables():
@@ -359,14 +392,14 @@ def _fused_body(
 
 
 def _fused_dequant_body(
-    batched, taus_ref, tauv_ref, w_ref, scales_ref, xs_ref, v_ref, z_ref,
-    out_ref, s_ref, norm_ref, sq_ref, cw_ref, dot_ref,
+    batched, taus_ref, tauv_ref, w_ref, scales_ref, xs_ref, v0_ref, z_ref,
+    out_ref, s_ref, norm_ref, sq_ref, cw_ref, dot_ref, v_ref,
 ):
     """Positional-ref adapter for the dequant variant: the sidecar scales
     ride as one extra VMEM operand between w and the wire-dtype xs."""
     _fused_body(
-        batched, taus_ref, tauv_ref, w_ref, xs_ref, v_ref, z_ref,
-        out_ref, s_ref, norm_ref, sq_ref, cw_ref, dot_ref,
+        batched, taus_ref, tauv_ref, w_ref, xs_ref, v0_ref, z_ref,
+        out_ref, s_ref, norm_ref, sq_ref, cw_ref, dot_ref, v_ref,
         scales_ref=scales_ref,
     )
 
@@ -380,7 +413,7 @@ def _pad_taus(taus, n_iters):
 
 def centered_clip_fused_pallas(
     xs, taus, z, tau_v=None, weights=None, v0=None, *,
-    block: int = DEFAULT_BLOCK, interpret: bool = True,
+    block: int = DEFAULT_BLOCK, interpret: bool | None = None,
 ):
     """Fused CenteredClip + verification tables in n_iters + 2 passes of x.
 
@@ -412,7 +445,7 @@ def centered_clip_fused_pallas(
         else v0.reshape(1, dp).astype(jnp.float32)
     )
 
-    out, s, norms = pl.pallas_call(
+    out, s, norms = _pallas_call(
         functools.partial(_fused_body, False),
         grid=(n_iters + 2, n_blocks),
         in_specs=[
@@ -420,11 +453,11 @@ def centered_clip_fused_pallas(
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((n, 1), lambda i, b: (0, 0)),
             pl.BlockSpec((n, blk), lambda i, b: (0, b)),
-            pl.BlockSpec((1, blk), lambda i, b: (0, b)),
+            _ANY,
             pl.BlockSpec((1, blk), lambda i, b: (0, b)),
         ],
         out_specs=[
-            pl.BlockSpec((1, blk), lambda i, b: (0, b)),
+            _ANY,
             pl.BlockSpec((n, 1), lambda i, b: (0, 0)),
             pl.BlockSpec((n, 1), lambda i, b: (0, 0)),
         ],
@@ -437,7 +470,9 @@ def centered_clip_fused_pallas(
             pltpu.VMEM((n, 1), jnp.float32),
             pltpu.VMEM((n, 1), jnp.float32),
             pltpu.VMEM((n, 1), jnp.float32),
+            pltpu.VMEM((1, blk), jnp.float32),
         ],
+        input_output_aliases={4: 0},
         interpret=interpret,
     )(_pad_taus(taus, n_iters), tauv2, w2, xs, v0, z.reshape(1, dp))
     return out[0, :d], s[:, 0], norms[:, 0]
@@ -445,7 +480,7 @@ def centered_clip_fused_pallas(
 
 def butterfly_clip_fused_pallas(
     parts, taus, z, tau_v=None, weights=None, v0=None, *,
-    block: int = DEFAULT_BLOCK, interpret: bool = True,
+    block: int = DEFAULT_BLOCK, interpret: bool | None = None,
 ):
     """All-partition fused ButterflyClip: the whole robust aggregation AND
     the Alg. 6 broadcast tables in ONE pallas_call of n_iters + 2 passes.
@@ -477,7 +512,7 @@ def butterfly_clip_fused_pallas(
         else v0.astype(jnp.float32).reshape(n_parts, 1, dp)
     )
 
-    out, s, norms = pl.pallas_call(
+    out, s, norms = _pallas_call(
         functools.partial(_fused_body, True),
         grid=(n_parts, n_iters + 2, n_blocks),
         in_specs=[
@@ -485,11 +520,11 @@ def butterfly_clip_fused_pallas(
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((n, 1), lambda p, i, b: (0, 0)),
             pl.BlockSpec((1, n, blk), lambda p, i, b: (p, 0, b)),
-            pl.BlockSpec((1, 1, blk), lambda p, i, b: (p, 0, b)),
+            _ANY,
             pl.BlockSpec((1, 1, blk), lambda p, i, b: (p, 0, b)),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, blk), lambda p, i, b: (p, 0, b)),
+            _ANY,
             pl.BlockSpec((1, 1, n), lambda p, i, b: (p, 0, 0)),
             pl.BlockSpec((1, 1, n), lambda p, i, b: (p, 0, 0)),
         ],
@@ -502,7 +537,9 @@ def butterfly_clip_fused_pallas(
             pltpu.VMEM((n, 1), jnp.float32),
             pltpu.VMEM((n, 1), jnp.float32),
             pltpu.VMEM((n, 1), jnp.float32),
+            pltpu.VMEM((1, blk), jnp.float32),
         ],
+        input_output_aliases={4: 0},
         interpret=interpret,
     )(_pad_taus(taus, n_iters), tauv2, w2, parts, v0,
       z.reshape(n_parts, 1, dp))
@@ -511,7 +548,7 @@ def butterfly_clip_fused_pallas(
 
 def butterfly_clip_fused_dequant_pallas(
     qs, scales, taus, z, tau_v=None, weights=None, v0=None, *,
-    block: int = DEFAULT_BLOCK, interpret: bool = True,
+    block: int = DEFAULT_BLOCK, interpret: bool | None = None,
 ):
     """The fused ButterflyClip aggregation + tables over WIRE payloads: qs
     stays int8/bf16 in HBM for all n_iters + 2 passes and is dequantized
@@ -553,7 +590,7 @@ def butterfly_clip_fused_dequant_pallas(
         else v0.astype(jnp.float32).reshape(n_parts, 1, dp)
     )
 
-    out, s, norms = pl.pallas_call(
+    out, s, norms = _pallas_call(
         functools.partial(_fused_dequant_body, True),
         grid=(n_parts, n_iters + 2, n_blocks),
         in_specs=[
@@ -562,11 +599,11 @@ def butterfly_clip_fused_dequant_pallas(
             pl.BlockSpec((n, 1), lambda p, i, b: (0, 0)),
             pl.BlockSpec((1, n, 1), lambda p, i, b: (p, 0, 0)),
             pl.BlockSpec((1, n, blk), lambda p, i, b: (p, 0, b)),
-            pl.BlockSpec((1, 1, blk), lambda p, i, b: (p, 0, b)),
+            _ANY,
             pl.BlockSpec((1, 1, blk), lambda p, i, b: (p, 0, b)),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, blk), lambda p, i, b: (p, 0, b)),
+            _ANY,
             pl.BlockSpec((1, 1, n), lambda p, i, b: (p, 0, 0)),
             pl.BlockSpec((1, 1, n), lambda p, i, b: (p, 0, 0)),
         ],
@@ -579,7 +616,9 @@ def butterfly_clip_fused_dequant_pallas(
             pltpu.VMEM((n, 1), jnp.float32),
             pltpu.VMEM((n, 1), jnp.float32),
             pltpu.VMEM((n, 1), jnp.float32),
+            pltpu.VMEM((1, blk), jnp.float32),
         ],
+        input_output_aliases={5: 0},
         interpret=interpret,
     )(_pad_taus(taus, n_iters), tauv2, w2, sc3, qs, v0,
       z.reshape(n_parts, 1, dp))
@@ -640,7 +679,7 @@ def _adaptive_step_kernel(
 
 def adaptive_clip_step_pallas(
     parts, v, sq, tau, weights=None, *,
-    block: int = DEFAULT_BLOCK, interpret: bool = True,
+    block: int = DEFAULT_BLOCK, interpret: bool | None = None,
 ):
     """One all-partition CenteredClip iteration (single HBM pass of parts).
 
@@ -661,7 +700,7 @@ def adaptive_clip_step_pallas(
 
     tau2 = jnp.asarray(tau, jnp.float32).reshape(1, 1)
     w2 = weights.reshape(n, 1).astype(jnp.float32)
-    return pl.pallas_call(
+    return _pallas_call(
         _adaptive_step_kernel,
         grid=(n_parts, n_blocks),
         in_specs=[
@@ -689,7 +728,7 @@ def adaptive_clip_step_pallas(
 
 def butterfly_clip_adaptive_pallas(
     parts, tau, tol, max_iters: int, weights=None, v0=None, *,
-    block: int = DEFAULT_BLOCK, interpret: bool = True,
+    block: int = DEFAULT_BLOCK, interpret: bool | None = None,
 ):
     """Early-exit all-partition CenteredClip: iterate the one-pass step
     kernel under ``lax.while_loop`` until every partition's update norm is
@@ -774,7 +813,8 @@ def _vt_kernel(tau_ref, xs_ref, v_ref, z_ref, s_ref, norm_ref, dot_ref, sq_ref):
 
 
 def verify_tables_pallas(
-    xs, v, z, tau, *, block: int = DEFAULT_BLOCK, interpret: bool = True
+    xs, v, z, tau, *, block: int = DEFAULT_BLOCK,
+    interpret: bool | None = None,
 ):
     """Fused s_i = <z, clip(x_i - v)>, norm_i = ||x_i - v|| in one pass.
 
@@ -790,7 +830,7 @@ def verify_tables_pallas(
     n_blocks = dp // blk
 
     tau2 = jnp.asarray(tau, jnp.float32).reshape(1, 1)
-    s, norms = pl.pallas_call(
+    s, norms = _pallas_call(
         _vt_kernel,
         grid=(n_blocks,),
         in_specs=[
@@ -875,7 +915,8 @@ def _dg_batched_kernel(xs_ref, v_ref, z_ref, s_ref, norm_ref, dot_ref, sq_ref):
 
 
 def digest_tables_batched_pallas(
-    parts, agg, z, *, block: int = DEFAULT_BLOCK, interpret: bool = True
+    parts, agg, z, *, block: int = DEFAULT_BLOCK,
+    interpret: bool | None = None,
 ):
     """All-partition generalized digests in one pass of the stacked parts.
 
@@ -891,7 +932,7 @@ def digest_tables_batched_pallas(
         z = jnp.pad(z, ((0, 0), (0, dp - d)))
     n_blocks = dp // blk
 
-    s, norms = pl.pallas_call(
+    s, norms = _pallas_call(
         _dg_batched_kernel,
         grid=(n_parts, n_blocks),
         in_specs=[
@@ -952,7 +993,7 @@ def _rows_digest_kernel(rows_ref, tau_ref, xs_ref, v_ref, z_ref, s_ref,
 
 def digest_tables_rows_pallas(
     parts, agg, z, rows, tau=0.0, *, block: int = DEFAULT_BLOCK,
-    interpret: bool = True
+    interpret: bool | None = None
 ):
     """Sampled-column digest tables in one pass of the SAMPLED partitions.
 
@@ -994,7 +1035,7 @@ def digest_tables_rows_pallas(
             pltpu.VMEM((n, 1), jnp.float32),
         ],
     )
-    s, norms = pl.pallas_call(
+    s, norms = _pallas_call(
         _rows_digest_kernel,
         grid_spec=grid_spec,
         out_shape=[
@@ -1014,54 +1055,50 @@ def digest_tables_rows_pallas(
 
 def _md_kernel(w_ref, xs_ref, z_ref, out_ref, s_ref, norm_ref, dot_ref,
                sq_ref, *, scales_ref=None):
-    """Grid (n_parts, 2, n_blocks) — fused weighted mean + digest epilogue.
+    """Grid (n_parts, n_blocks) — fused weighted mean + digest tables.
 
-    Phase 0 writes the per-partition weighted mean block-locally (the mean
-    decomposes over lanes — no cross-block scratch needed); phase 1 streams
-    x once more against the finished aggregate accumulating the per-peer
-    digest dot and squared norm, emitting both tables on the last block.
-    2 HBM passes of x, zero materialized (n, d) temporaries.
+    The per-partition weighted mean decomposes over lanes, so each block's
+    aggregate is final as soon as it is computed; the per-peer digest dot
+    and squared norm accumulate against it in the same step, and both
+    tables are emitted on the last block. 1 HBM pass of x, zero
+    materialized (n, d) temporaries.
 
     scales_ref (dequant variant): xs arrives in its wire dtype (int8/bf16)
     and both phases see ``xs.astype(f32) * scale`` — the exact formula of
     core.compression.dequantize, so aggregate and digests are computed over
     the dequantized-from-wire values (compressed:verified:mean)."""
-    phase = pl.program_id(1)
-    blk = pl.program_id(2)
-    nb = pl.num_programs(2)
+    blk = pl.program_id(1)
+    nb = pl.num_programs(1)
     xs = xs_ref[0].astype(jnp.float32)
     if scales_ref is not None:  # in-register dequantize of the wire payload
         xs = xs * scales_ref[0]
+    w = w_ref[...].astype(jnp.float32)
+    wsum = jnp.maximum(jnp.sum(w), 1e-30)
+    agg = jnp.sum(w * xs, axis=0, keepdims=True) / wsum
+    out_ref[0] = agg
 
-    @pl.when(phase == 0)
-    def _aggregate():
-        w = w_ref[...].astype(jnp.float32)
-        wsum = jnp.maximum(jnp.sum(w), 1e-30)
-        out_ref[0] = jnp.sum(w * xs, axis=0, keepdims=True) / wsum
+    @pl.when(blk == 0)
+    def _reset():
+        dot_ref[...] = jnp.zeros_like(dot_ref)
+        sq_ref[...] = jnp.zeros_like(sq_ref)
 
-    @pl.when(phase == 1)
-    def _digest():
-        @pl.when(blk == 0)
-        def _reset():
-            dot_ref[...] = jnp.zeros_like(dot_ref)
-            sq_ref[...] = jnp.zeros_like(sq_ref)
+    diff = xs - agg
+    dot_ref[...] += jnp.sum(
+        diff * z_ref[0].astype(jnp.float32), axis=1, keepdims=True
+    )
+    sq_ref[...] += jnp.sum(diff * diff, axis=1, keepdims=True)
 
-        diff = xs - out_ref[0]
-        dot_ref[...] += jnp.sum(
-            diff * z_ref[0].astype(jnp.float32), axis=1, keepdims=True
+    @pl.when(blk == nb - 1)
+    def _epilogue():
+        s_ref[0] = dot_ref[...].reshape(s_ref.shape[1:])
+        norm_ref[0] = jnp.sqrt(jnp.maximum(sq_ref[...], 0.0)).reshape(
+            norm_ref.shape[1:]
         )
-        sq_ref[...] += jnp.sum(diff * diff, axis=1, keepdims=True)
-
-        @pl.when(blk == nb - 1)
-        def _epilogue():
-            s_ref[0] = dot_ref[...].reshape(s_ref.shape[1:])
-            norm_ref[0] = jnp.sqrt(jnp.maximum(sq_ref[...], 0.0)).reshape(
-                norm_ref.shape[1:]
-            )
 
 
 def mean_digest_fused_pallas(
-    parts, z, weights=None, *, block: int = DEFAULT_BLOCK, interpret: bool = True
+    parts, z, weights=None, *, block: int = DEFAULT_BLOCK,
+    interpret: bool | None = None,
 ):
     """verified:mean's fused aggregation + digest tables in one pallas_call.
 
@@ -1079,18 +1116,18 @@ def mean_digest_fused_pallas(
     n_blocks = dp // blk
 
     w2 = weights.reshape(n, 1).astype(jnp.float32)
-    agg, s, norms = pl.pallas_call(
+    agg, s, norms = _pallas_call(
         _md_kernel,
-        grid=(n_parts, 2, n_blocks),
+        grid=(n_parts, n_blocks),
         in_specs=[
-            pl.BlockSpec((n, 1), lambda p, ph, b: (0, 0)),
-            pl.BlockSpec((1, n, blk), lambda p, ph, b: (p, 0, b)),
-            pl.BlockSpec((1, 1, blk), lambda p, ph, b: (p, 0, b)),
+            pl.BlockSpec((n, 1), lambda p, b: (0, 0)),
+            pl.BlockSpec((1, n, blk), lambda p, b: (p, 0, b)),
+            pl.BlockSpec((1, 1, blk), lambda p, b: (p, 0, b)),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, blk), lambda p, ph, b: (p, 0, b)),
-            pl.BlockSpec((1, 1, n), lambda p, ph, b: (p, 0, 0)),
-            pl.BlockSpec((1, 1, n), lambda p, ph, b: (p, 0, 0)),
+            pl.BlockSpec((1, 1, blk), lambda p, b: (p, 0, b)),
+            pl.BlockSpec((1, 1, n), lambda p, b: (p, 0, 0)),
+            pl.BlockSpec((1, 1, n), lambda p, b: (p, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((n_parts, 1, dp), jnp.float32),
@@ -1119,10 +1156,10 @@ def _md_dequant_kernel(
 
 def mean_digest_fused_dequant_pallas(
     qs, scales, z, weights=None, *,
-    block: int = DEFAULT_BLOCK, interpret: bool = True,
+    block: int = DEFAULT_BLOCK, interpret: bool | None = None,
 ):
     """compressed:verified:mean's fused aggregation + digests over WIRE
-    payloads: qs stays int8/bf16 in HBM for both passes, dequantized
+    payloads: qs stays int8/bf16 in HBM for its one pass, dequantized
     in-register against the sidecar scales (see
     butterfly_clip_fused_dequant_pallas for the tiling argument).
 
@@ -1142,19 +1179,19 @@ def mean_digest_fused_dequant_pallas(
 
     w2 = weights.reshape(n, 1).astype(jnp.float32)
     sc3 = scales.reshape(n_parts, n, 1).astype(jnp.float32)
-    agg, s, norms = pl.pallas_call(
+    agg, s, norms = _pallas_call(
         _md_dequant_kernel,
-        grid=(n_parts, 2, n_blocks),
+        grid=(n_parts, n_blocks),
         in_specs=[
-            pl.BlockSpec((n, 1), lambda p, ph, b: (0, 0)),
-            pl.BlockSpec((1, n, 1), lambda p, ph, b: (p, 0, 0)),
-            pl.BlockSpec((1, n, blk), lambda p, ph, b: (p, 0, b)),
-            pl.BlockSpec((1, 1, blk), lambda p, ph, b: (p, 0, b)),
+            pl.BlockSpec((n, 1), lambda p, b: (0, 0)),
+            pl.BlockSpec((1, n, 1), lambda p, b: (p, 0, 0)),
+            pl.BlockSpec((1, n, blk), lambda p, b: (p, 0, b)),
+            pl.BlockSpec((1, 1, blk), lambda p, b: (p, 0, b)),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, blk), lambda p, ph, b: (p, 0, b)),
-            pl.BlockSpec((1, 1, n), lambda p, ph, b: (p, 0, 0)),
-            pl.BlockSpec((1, 1, n), lambda p, ph, b: (p, 0, 0)),
+            pl.BlockSpec((1, 1, blk), lambda p, b: (p, 0, b)),
+            pl.BlockSpec((1, 1, n), lambda p, b: (p, 0, 0)),
+            pl.BlockSpec((1, 1, n), lambda p, b: (p, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((n_parts, 1, dp), jnp.float32),
@@ -1171,7 +1208,8 @@ def mean_digest_fused_dequant_pallas(
 
 
 def verify_tables_batched_pallas(
-    parts, agg, z, tau, *, block: int = DEFAULT_BLOCK, interpret: bool = True
+    parts, agg, z, tau, *, block: int = DEFAULT_BLOCK,
+    interpret: bool | None = None,
 ):
     """All-partition verification tables in one pass of the stacked parts.
 
@@ -1188,7 +1226,7 @@ def verify_tables_batched_pallas(
     n_blocks = dp // blk
 
     tau2 = jnp.asarray(tau, jnp.float32).reshape(1, 1)
-    s, norms = pl.pallas_call(
+    s, norms = _pallas_call(
         _vt_batched_kernel,
         grid=(n_parts, n_blocks),
         in_specs=[

@@ -1,19 +1,17 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-On CPU (this container) the kernels run with interpret=True; on a real TPU
-set ``REPRO_PALLAS_COMPILE=1`` (or pass interpret=False) to lower natively.
+Each kernel compiles natively when the program is lowered for a TPU and runs
+in the Pallas interpreter when it is lowered for the CPU; any other platform
+is an error (``centered_clip._pallas_call``).
 """
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
 
 from repro.kernels import centered_clip as _k
-
-_INTERPRET = os.environ.get("REPRO_PALLAS_COMPILE", "0") != "1"
 
 
 @functools.partial(jax.jit, static_argnames=("n_iters", "block"))
@@ -24,14 +22,14 @@ def centered_clip_op(
     v0: optional (d,) warm start (previous aggregate)."""
     taus = jnp.broadcast_to(jnp.asarray(tau, jnp.float32), (n_iters,))
     return _k.centered_clip_pallas(
-        xs, taus, weights, v0, block=block, interpret=_INTERPRET
+        xs, taus, weights, v0, block=block,
     )
 
 
 @functools.partial(jax.jit, static_argnames=("block",))
 def verify_tables_op(xs, v, z, tau, *, block: int = _k.DEFAULT_BLOCK):
     """Kernel-backed fused verification tables."""
-    return _k.verify_tables_pallas(xs, v, z, tau, block=block, interpret=_INTERPRET)
+    return _k.verify_tables_pallas(xs, v, z, tau, block=block)
 
 
 @functools.partial(jax.jit, static_argnames=("n_iters", "block"))
@@ -43,7 +41,7 @@ def butterfly_clip_op(
     v0: optional (n_parts, part) warm start (previous aggregate)."""
     taus = jnp.broadcast_to(jnp.asarray(tau, jnp.float32), (n_iters,))
     return _k.butterfly_clip_pallas(
-        parts, taus, weights, v0, block=block, interpret=_INTERPRET
+        parts, taus, weights, v0, block=block,
     )
 
 
@@ -61,7 +59,7 @@ def centered_clip_fused_op(
     taus = jnp.broadcast_to(jnp.asarray(tau, jnp.float32), (n_iters,))
     return _k.centered_clip_fused_pallas(
         xs, taus, z, tau_v=tau_v, weights=weights, v0=v0,
-        block=block, interpret=_INTERPRET,
+        block=block,
     )
 
 
@@ -79,7 +77,7 @@ def butterfly_clip_fused_op(
     taus = jnp.broadcast_to(jnp.asarray(tau, jnp.float32), (n_iters,))
     agg, s, norms = _k.butterfly_clip_fused_pallas(
         parts, taus, z, tau_v=tau_v, weights=weights, v0=v0,
-        block=block, interpret=_INTERPRET,
+        block=block,
     )
     return agg, s.T, norms.T
 
@@ -99,7 +97,7 @@ def butterfly_clip_fused_dequant_op(
     taus = jnp.broadcast_to(jnp.asarray(tau, jnp.float32), (n_iters,))
     agg, s, norms = _k.butterfly_clip_fused_dequant_pallas(
         qs, scales, taus, z, tau_v=tau_v, weights=weights, v0=v0,
-        block=block, interpret=_INTERPRET,
+        block=block,
     )
     return agg, s.T, norms.T
 
@@ -120,7 +118,7 @@ def butterfly_clip_adaptive_op(
     iters (n_parts,) i32). v0: optional warm start (previous aggregate)."""
     return _k.butterfly_clip_adaptive_pallas(
         parts, tau, tol, max_iters, weights, v0,
-        block=block, interpret=_INTERPRET,
+        block=block,
     )
 
 
@@ -138,10 +136,10 @@ def butterfly_clip_fused_adaptive_op(
     (peer, partition) layout of core.butterfly.verification_tables."""
     agg, iters = _k.butterfly_clip_adaptive_pallas(
         parts, tau, tol, max_iters, weights, v0,
-        block=block, interpret=_INTERPRET,
+        block=block,
     )
     s, norms = _k.verify_tables_batched_pallas(
-        parts, agg, z, tau, block=block, interpret=_INTERPRET
+        parts, agg, z, tau, block=block,
     )
     return agg, s.T, norms.T, iters
 
@@ -151,7 +149,7 @@ def verify_tables_all_op(parts, agg, z, tau, *, block: int = _k.DEFAULT_BLOCK):
     """Kernel-backed all-partition verification tables (one pass of parts):
     -> (s (n_peers, n_parts), norms (n_peers, n_parts))."""
     s, norms = _k.verify_tables_batched_pallas(
-        parts, agg, z, tau, block=block, interpret=_INTERPRET
+        parts, agg, z, tau, block=block,
     )
     return s.T, norms.T
 
@@ -167,7 +165,7 @@ def digest_tables_all_op(parts, agg, z, *, block: int = _k.DEFAULT_BLOCK):
     -> (s (n_peers, n_parts), norms (n_peers, n_parts)) — the standalone
     digest pass for verified:* specs whose aggregation runs in jnp."""
     s, norms = _k.digest_tables_batched_pallas(
-        parts, agg, z, block=block, interpret=_INTERPRET
+        parts, agg, z, block=block,
     )
     return s.T, norms.T
 
@@ -183,7 +181,7 @@ def digest_tables_rows_op(parts, agg, z, rows, tau=0.0, *,
     weight; tau == 0 emits the plain verified:* digests. One HBM pass of
     the k sampled partitions only (scalar-prefetched row ids)."""
     s, norms = _k.digest_tables_rows_pallas(
-        parts, agg, z, rows, tau, block=block, interpret=_INTERPRET
+        parts, agg, z, rows, tau, block=block,
     )
     return s.T, norms.T
 
@@ -191,14 +189,14 @@ def digest_tables_rows_op(parts, agg, z, rows, tau=0.0, *,
 @functools.partial(jax.jit, static_argnames=("block",))
 def mean_digest_fused_op(parts, z, weights=None, *, block: int = _k.DEFAULT_BLOCK):
     """verified:mean's fused aggregation + digest epilogue in ONE
-    pallas_call (2 HBM passes of the stacked partitions, zero materialized
+    pallas_call (1 HBM pass of the stacked partitions, zero materialized
     temporaries): parts (n_parts, n_peers, part), z (n_parts, part) ->
     (agg (n_parts, part), s (n_peers, n_parts), norms (n_peers, n_parts)).
 
     s/norms come back transposed to the (peer, partition) layout of
     core.verification.digest_tables."""
     agg, s, norms = _k.mean_digest_fused_pallas(
-        parts, z, weights, block=block, interpret=_INTERPRET
+        parts, z, weights, block=block,
     )
     return agg, s.T, norms.T
 
@@ -213,6 +211,6 @@ def mean_digest_fused_dequant_op(
     the (n_parts, n_peers) f32 sidecar scales. Returns (agg, s, norms) in
     the mean_digest_fused_op layout."""
     agg, s, norms = _k.mean_digest_fused_dequant_pallas(
-        qs, scales, z, weights, block=block, interpret=_INTERPRET
+        qs, scales, z, weights, block=block,
     )
     return agg, s.T, norms.T

@@ -29,13 +29,14 @@ def main():
 
     from repro.configs.base import InputShape
     from repro.data import TokenPipeline
+    from repro.launch.mesh import make_mesh
     from repro.launch.steps import make_decode_step, make_prefill_step
     from repro.models import get_model
     from repro.sharding import set_mesh
 
     dims = [int(x) for x in args.mesh.split("x")]
     names = ("data", "model") if len(dims) == 2 else ("pod", "data", "model")
-    mesh = jax.make_mesh(tuple(dims), names)
+    mesh = make_mesh(dims, names)
     set_mesh(mesh)
 
     model = get_model(args.arch, reduced=args.reduced)

@@ -47,24 +47,6 @@ def _named(mesh, spec_tree):
     )
 
 
-def _shard_map(f, mesh, in_specs, out_specs, axis_names, check_vma=False):
-    """jax.shard_map with a fallback to the pre-0.5 experimental API, where
-    the manual-axes set is expressed as its complement (``auto``) and
-    check_vma was called check_rep."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            axis_names=set(axis_names), check_vma=check_vma,
-        )
-    from jax.experimental.shard_map import shard_map as _sm
-
-    auto = frozenset(mesh.axis_names) - frozenset(axis_names)
-    return _sm(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=check_vma, auto=auto,
-    )
-
-
 def opt_state_specs(opt_state_abs, pspecs):
     """Optimizer state mirrors the param tree per moment buffer."""
 
@@ -147,13 +129,15 @@ def _collapse_peer_mesh(mesh):
     peer_axes = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
     if len(peer_axes) <= 1:
         return mesh, peer_axes
-    from jax.sharding import Mesh
+    from jax.sharding import AxisType, Mesh
 
     other = tuple(a for a in mesh.axis_names if a not in peer_axes)
     perm = [mesh.axis_names.index(a) for a in peer_axes + other]
     devs = np.transpose(mesh.devices, perm)
     devs = devs.reshape((-1,) + devs.shape[len(peer_axes):])
-    return Mesh(devs, ("peers",) + other), ("peers",)
+    names = ("peers",) + other
+    mesh = Mesh(devs, names, axis_types=(AxisType.Auto,) * len(names))
+    return mesh, ("peers",)
 
 
 def aggregation_stage(
@@ -715,7 +699,7 @@ def _build_btard_step(
             set_manual_axes(())
         return loss[None], jax.tree.map(lambda g: g[None], grads)
 
-    stage1 = _shard_map(
+    stage1 = jax.shard_map(
         peer_grads,
         mesh=mesh,
         in_specs=(jax.tree.map(lambda s: P(), pspecs, is_leaf=_is_p), _peer_lead(bspecs, peer_axes)),
@@ -781,7 +765,7 @@ def _build_btard_step(
         lambda s: P(peer_axes, *s), pspecs, is_leaf=_is_p
     )
     agg_specs = pspecs  # the aggregate tree shards exactly like the params
-    stage2 = _shard_map(
+    stage2 = jax.shard_map(
         butterfly_all,
         mesh=mesh,
         in_specs=(manual_pspecs, P(), P(), P(), P())

@@ -73,7 +73,9 @@ def resolve_cli_aggregator(text, warm_start_clip=False, adaptive_clip=None,
     return with_byzantine_default(spec, n_byzantine)
 
 
-def main():
+def main(argv=None):
+    """Run the CLI on ``argv`` (default ``sys.argv[1:]``) and return the
+    SUMMARY dict it prints last."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduced", action="store_true")
@@ -189,7 +191,7 @@ def main():
                          "--resume to verify recovery)")
     ap.add_argument("--checkpoint", default="")
     ap.add_argument("--log-every", type=int, default=1)
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
     if args.host_devices:
         os.environ["XLA_FLAGS"] = (
@@ -197,6 +199,10 @@ def main():
         )
 
     byz = set(int(x) for x in args.byzantine.split(",") if x)
+
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     import json
 
@@ -209,6 +215,7 @@ def main():
     from repro.core import butterfly as bf
     from repro.core.sybil import HostMembership, parse_churn
     from repro.data import TokenPipeline
+    from repro.launch.mesh import make_mesh
     from repro.launch.steps import (
         make_baseline_train_step,
         make_btard_scan_train_step,
@@ -221,7 +228,7 @@ def main():
 
     dims = [int(x) for x in args.mesh.split("x")]
     names = ("data", "model") if len(dims) == 2 else ("pod", "data", "model")
-    mesh = jax.make_mesh(tuple(dims), names)
+    mesh = make_mesh(dims, names)
     set_mesh(mesh)
     set_seq_parallel(args.seq_parallel)
 
@@ -435,8 +442,8 @@ def main():
                     print(f"halt requested at step {args.halt_at}: "
                           f"checkpointed step {next_step}, exiting "
                           "(resume with --resume)", flush=True)
-                    _print_summary(json, mem, byz, final_loss, next_step)
-                    return
+                    return _print_summary(json, mem, byz, final_loss,
+                                          next_step)
     else:
         for step in range(args.steps):
             mem.apply_events(step)
@@ -471,19 +478,22 @@ def main():
                       flush=True)
     dt = time.time() - t0
     print(f"done: {args.steps} steps in {dt:.1f}s ({dt/args.steps:.2f}s/step)")
-    _print_summary(json, mem, byz, final_loss, args.steps)
+    summary = _print_summary(json, mem, byz, final_loss, args.steps)
     if args.checkpoint:
         save_checkpoint(args.checkpoint, {"params": params, "opt": opt_state},
                         step=args.steps, meta={"arch": args.arch})
         print("checkpoint saved:", args.checkpoint)
+    return summary
 
 
 def _print_summary(json, mem, byz, final_loss, steps_done):
-    """One machine-parseable line for CI assertions (churn gauntlet)."""
+    """One machine-parseable line for CI assertions (churn gauntlet);
+    returns the dict it prints."""
     s = mem.summary()
     s.update(byzantine=sorted(byz), final_loss=final_loss,
              steps_done=int(steps_done))
     print("SUMMARY " + json.dumps(s), flush=True)
+    return s
 
 
 if __name__ == "__main__":

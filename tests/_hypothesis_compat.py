@@ -10,7 +10,7 @@ fails on the import.
 from __future__ import annotations
 
 try:  # pragma: no cover - exercised only when hypothesis is installed
-    from hypothesis import given, settings, strategies  # noqa: F401
+    from hypothesis import example, given, settings, strategies  # noqa: F401
 
     HAVE_HYPOTHESIS = True
 except ImportError:
@@ -45,6 +45,11 @@ except ImportError:
             return _Strategy(lambda rng: rng.random() < 0.5)
 
     _DEFAULT_MAX_EXAMPLES = 10
+
+    def example(**_pinned):
+        """Pinned examples are a hypothesis feature; the fallback sampler
+        draws its own."""
+        return lambda fn: fn
 
     def settings(max_examples=_DEFAULT_MAX_EXAMPLES, **_ignored):
         """Accepts (and mostly ignores) the hypothesis knobs; only

@@ -23,7 +23,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
-from jax.experimental.shard_map import shard_map
 from jax.sharding import AbstractMesh, PartitionSpec as P
 
 from tools.analysis import common
@@ -82,9 +81,9 @@ def test_clean_phase_has_no_purity_findings():
 
 def _gather_harness(body):
     """Trace body(x) under a 1-axis abstract mesh, x one bf16 shard."""
-    mesh = AbstractMesh((("peers", 8),))
-    fn = shard_map(body, mesh=mesh, in_specs=(P("peers"),),
-                   out_specs=P(), check_rep=False)
+    mesh = AbstractMesh((8,), ("peers",))
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(P("peers"),),
+                       out_specs=P(), check_vma=False)
     return jax.make_jaxpr(fn)(jax.ShapeDtypeStruct((64,), jnp.bfloat16))
 
 

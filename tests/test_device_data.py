@@ -142,13 +142,14 @@ def test_launch_scan_device_data_equals_host_batches():
     env["PYTHONPATH"] = os.path.join(REPO, "src")
     code = """
     import jax, jax.numpy as jnp
+    from repro.launch.mesh import make_mesh
     from repro.launch.steps import make_btard_scan_train_step
     from repro.models import get_model
     from repro.optim import sgd
     from repro.configs.base import InputShape
     from repro.data import TokenPipeline
 
-    mesh = jax.make_mesh((4, 2), ('data', 'model'))
+    mesh = make_mesh((4, 2), ('data', 'model'))
     m = get_model('qwen3-1.7b', reduced=True)
     shape = InputShape('t', 16, 8, 'train')
     opt = sgd(0.05)

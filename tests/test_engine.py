@@ -133,6 +133,23 @@ def test_scan_delayed_gradient_ring_buffer():
     )
 
 
+@pytest.mark.parametrize("kind", ["sign_flip", "none", "delayed_gradient"])
+def test_gradient_history_only_for_delayed_attack(kind):
+    """At albert-large's width (d = 78,223,360, 4 peers) only a
+    delayed_gradient run carries the (delay, n, d) history, and only it can
+    trip the carry-size guard; every other attack carries an empty buffer
+    and starts. Shapes only (eval_shape): nothing full-size is allocated."""
+    cfg = eng.config_from_attack(
+        4, 78_223_360, AttackConfig(kind=kind, start_step=0, delay=5))
+    if kind == "delayed_gradient":
+        with pytest.raises(ValueError, match="ring buffer"):
+            jax.eval_shape(lambda: eng.init_state(cfg))
+        return
+    state = jax.eval_shape(lambda: eng.init_state(cfg))
+    assert cfg.delay_depth == 0
+    assert state.delay_buf.shape == (0, 4, 78_223_360)
+
+
 def test_scan_no_attack_no_bans_and_stable():
     state, outs = _run_scan(AttackConfig(kind="none"))
     assert not np.any(np.asarray(state.ban_step) >= 0)

@@ -94,3 +94,54 @@ def test_property_block_size_invariance(n, d, blk, seed):
     a = centered_clip_op(xs, 1.0, n_iters=8, block=blk)
     b = centered_clip_op(xs, 1.0, n_iters=8, block=2048)
     np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-5)
+
+
+def _multi_pass_cases():
+    """The kernels that revisit each lane block in several grid passes."""
+    from repro.core import compression as comp
+    from repro.kernels import centered_clip as k
+
+    xs = jax.random.normal(jax.random.key(40), (2, 4, 384)) * 3
+    z = jax.random.normal(jax.random.key(41), (2, 384))
+    v0 = jax.random.normal(jax.random.key(42), (2, 384))
+    taus = jnp.full((3,), 1.0, jnp.float32)
+    qs, sc = comp.quantize(xs, "int8")
+    kw = dict(block=128)  # 3 lane blocks: every pass revisits each block
+    return {
+        "centered_clip": lambda m: k.centered_clip_pallas(
+            xs[0], taus, v0=v0[0], interpret=m, **kw),
+        "butterfly_clip": lambda m: k.butterfly_clip_pallas(
+            xs, taus, interpret=m, **kw),
+        "centered_clip_fused": lambda m: k.centered_clip_fused_pallas(
+            xs[0], taus, z[0], interpret=m, **kw),
+        "butterfly_clip_fused": lambda m: k.butterfly_clip_fused_pallas(
+            xs, taus, z, v0=v0, interpret=m, **kw),
+        "butterfly_clip_fused_dequant": lambda m: (
+            k.butterfly_clip_fused_dequant_pallas(
+                qs, sc, taus, z, interpret=m, **kw)),
+        "mean_digest_fused": lambda m: k.mean_digest_fused_pallas(
+            xs, z, interpret=m, **kw),
+        "mean_digest_fused_dequant": lambda m: (
+            k.mean_digest_fused_dequant_pallas(qs, sc, z, interpret=m, **kw)),
+    }
+
+
+@pytest.mark.parametrize("kernel", [
+    "centered_clip", "butterfly_clip", "centered_clip_fused",
+    "butterfly_clip_fused", "butterfly_clip_fused_dequant",
+    "mean_digest_fused", "mean_digest_fused_dequant",
+])
+def test_multi_pass_kernel_under_tpu_pipeline_semantics(kernel):
+    """The TPU pipeline writes an output block back to HBM and never reads
+    it in again, so a kernel that carried its iterate in an output block
+    read stale VMEM on the chip while the plain interpreter (which reads the
+    whole output array) hid it. The TPU interpreter models the pipeline and
+    refuses a revisited output block; under it every multi-pass kernel must
+    equal the plain interpreter bitwise."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    run = _multi_pass_cases()[kernel]
+    want = run(True)
+    got = run(pltpu.InterpretParams())
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))

@@ -1,233 +1,188 @@
-"""Native (non-interpret) TPU lowering validation for the kernel family.
+"""Native TPU compiles of the kernel family, with no TPU attached.
 
-Interpret mode hides an entire class of kernel bugs — block shapes that
-violate the TPU (8, 128) tile minimum, scalar operands that must live in
-SMEM, sublane-1 slices of batched outputs. These tests push every kernel
-through the REAL Mosaic lowering pipeline:
+Interpret mode hides a whole class of kernel bugs: block shapes that break
+the TPU (8, 128) tile minimum, scalar operands that must live in SMEM,
+sublane-1 slices of batched outputs, more VMEM than a kernel may use. Every
+test here compiles its kernel with the TPU compiler for one chip of a
+described v5e:2x2 topology (``jax.experimental.topologies``) and checks
+that the Mosaic kernel (``tpu_custom_call``) is in the compiled program.
+Nothing runs, so results are checked by the interpret-mode tests
+(tests/test_kernels.py); the spec-dispatch tests here also compare the
+CPU-interpreted kernels with the jnp path.
 
-* on a TPU host (``jax.default_backend() == 'tpu'``): compile AND run
-  natively, comparing against interpret mode;
-* on a CPU-only host: cross-platform lowering via the jax export API with
-  ``platforms=['tpu']`` — runs the full Mosaic pass (this is what caught
-  the original (1, 1)-blocked tau operands), no TPU needed;
-* skipped only when neither a TPU nor the export API exists.
-
-CI exercises this file under ``REPRO_PALLAS_COMPILE=1`` (see
-.github/workflows/ci.yml); the env-flag wiring itself is covered by the
-subprocess test at the bottom.
+The topology is described inside a module-scoped fixture, never while a
+module is imported: only one process at a time may load the TPU library,
+and pytest-xdist workers must all collect the same tests.
 """
-import os
-import subprocess
-import sys
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import SingleDeviceSharding
 
 from repro.kernels import centered_clip as _k
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
+@pytest.fixture(scope="module")
+def one_chip():
+    """One chip of a described v5e:2x2, with the persistent compile cache
+    off (a program compiled for a described chip cannot be read back)."""
+    import os
 
-def _export_fn():
-    """The cross-platform export entry point, wherever this jax hides it."""
-    exp = getattr(jax, "export", None)
-    if exp is not None and hasattr(exp, "export"):
-        return exp.export
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
     try:
-        from jax._src.export import _export
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
 
-        return _export.export
-    except ImportError:
-        return None
 
-
-def _on_tpu():
-    return jax.default_backend() == "tpu"
-
-
-def _validate(fn, *args):
-    """Native-compile fn on TPU, else Mosaic-lower it via export."""
-    jitted = jax.jit(fn)
-    if _on_tpu():
-        return jax.tree.map(np.asarray, jitted(*args))
-    exporter = _export_fn()
-    if exporter is None:
-        pytest.skip("no TPU and no cross-platform export API in this jax")
-    module = exporter(jitted, platforms=["tpu"])(*args).mlir_module()
-    assert "tpu_custom_call" in module  # the Mosaic kernel made it through
-    return None
+def _validate(one_chip, fn, *args):
+    """Compile fn for the described chip; args are arrays or shapes.
+    Returns the compiled program."""
+    shapes = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+              for a in args]
+    compiled = jax.jit(fn).lower(*shapes).compile()
+    assert "tpu_custom_call" in compiled.as_text()  # the Mosaic kernel
+    return compiled
 
 
 N, D, PARTS, ITERS = 8, 384, 4, 5
+# albert-large (78,223,360 params) split over the engine's 4 simulated
+# peers: each owner aggregates a (4 peers, d/4) stack
+ALBERT_D, ENGINE_PEERS = 78_223_360, 4
 
 
 def _stack(key, shape):
     return jax.random.normal(jax.random.key(key), shape, jnp.float32)
 
 
-def test_centered_clip_lowers_natively():
-    xs = _stack(0, (N, D))
+def test_centered_clip_lowers_natively(one_chip):
     taus = jnp.full((ITERS,), 1.0, jnp.float32)
-    out = _validate(
-        lambda x: _k.centered_clip_pallas(x, taus, interpret=False), xs
-    )
-    if out is not None:
-        ref = _k.centered_clip_pallas(xs, taus, interpret=True)
-        np.testing.assert_allclose(out, np.asarray(ref), atol=1e-5)
+    _validate(one_chip,
+              lambda x: _k.centered_clip_pallas(x, taus, interpret=False),
+              _stack(0, (N, D)))
 
 
-def test_butterfly_clip_lowers_natively():
-    parts = _stack(1, (PARTS, N, D))
+def test_butterfly_clip_lowers_natively(one_chip):
     taus = jnp.full((ITERS,), 1.0, jnp.float32)
-    out = _validate(
-        lambda p: _k.butterfly_clip_pallas(p, taus, interpret=False), parts
-    )
-    if out is not None:
-        ref = _k.butterfly_clip_pallas(parts, taus, interpret=True)
-        np.testing.assert_allclose(out, np.asarray(ref), atol=1e-5)
+    _validate(one_chip,
+              lambda p: _k.butterfly_clip_pallas(p, taus, interpret=False),
+              _stack(1, (PARTS, N, D)))
 
 
 @pytest.mark.parametrize("warm", [False, True])
-def test_fused_butterfly_lowers_natively(warm):
-    parts = _stack(2, (PARTS, N, D))
-    z = _stack(3, (PARTS, D))
-    v0 = _stack(4, (PARTS, D)) if warm else None
+def test_fused_butterfly_lowers_natively(one_chip, warm):
     taus = jnp.full((ITERS,), 1.0, jnp.float32)
 
-    def fn(p, zz):
+    def fn(p, zz, *v0):
         return _k.butterfly_clip_fused_pallas(
-            p, taus, zz, v0=v0, interpret=False
+            p, taus, zz, v0=v0[0] if warm else None, interpret=False
         )
 
-    out = _validate(fn, parts, z)
-    if out is not None:
-        ref = _k.butterfly_clip_fused_pallas(
-            parts, taus, z, v0=v0, interpret=True
-        )
-        for got, want in zip(out, ref):
-            np.testing.assert_allclose(got, np.asarray(want), atol=1e-4)
+    args = [_stack(2, (PARTS, N, D)), _stack(3, (PARTS, D))]
+    if warm:
+        args.append(_stack(4, (PARTS, D)))
+    _validate(one_chip, fn, *args)
 
 
-def test_fused_single_lowers_natively():
-    xs = _stack(5, (N, D))
-    z = _stack(6, (D,))
+def test_fused_single_lowers_natively(one_chip):
     taus = jnp.full((ITERS,), 1.0, jnp.float32)
     _validate(
+        one_chip,
         lambda x, zz: _k.centered_clip_fused_pallas(
             x, taus, zz, interpret=False
         ),
-        xs, z,
+        _stack(5, (N, D)), _stack(6, (D,)),
     )
 
 
-def test_verify_tables_batched_lowers_natively():
-    parts = _stack(7, (PARTS, N, D))
-    agg = _stack(8, (PARTS, D))
-    z = _stack(9, (PARTS, D))
+def test_verify_tables_batched_lowers_natively(one_chip):
     _validate(
+        one_chip,
         lambda p, a, zz: _k.verify_tables_batched_pallas(
             p, a, zz, 1.0, interpret=False
         ),
-        parts, agg, z,
+        _stack(7, (PARTS, N, D)), _stack(8, (PARTS, D)), _stack(9, (PARTS, D)),
     )
 
 
-def test_verify_tables_lowers_natively():
+def test_verify_tables_lowers_natively(one_chip):
     """The single (unbatched) verification kernel — its SMEM tau operand
     is exactly the (1, 1)-block class the Mosaic pass rejects."""
-    xs = _stack(20, (N, D))
-    v = _stack(21, (D,))
-    z = _stack(22, (D,))
-    out = _validate(
+    _validate(
+        one_chip,
         lambda x, vv, zz: _k.verify_tables_pallas(
             x, vv, zz, 1.0, interpret=False
         ),
-        xs, v, z,
+        _stack(20, (N, D)), _stack(21, (D,)), _stack(22, (D,)),
     )
-    if out is not None:
-        ref = _k.verify_tables_pallas(xs, v, z, 1.0, interpret=True)
-        for got, want in zip(out, ref):
-            np.testing.assert_allclose(got, np.asarray(want), atol=1e-5)
 
 
-def test_digest_tables_batched_lowers_natively():
+def test_digest_tables_batched_lowers_natively(one_chip):
     """The generalized verification wrapper's standalone digest pass
-    (s_i = <z, x_i - v>, ||x_i - v||, no clip weight) through the real
-    Mosaic pipeline."""
-    parts = _stack(16, (PARTS, N, D))
-    agg = _stack(17, (PARTS, D))
-    z = _stack(18, (PARTS, D))
-    out = _validate(
+    (s_i = <z, x_i - v>, ||x_i - v||, no clip weight)."""
+    _validate(
+        one_chip,
         lambda p, a, zz: _k.digest_tables_batched_pallas(
             p, a, zz, interpret=False
         ),
-        parts, agg, z,
+        _stack(16, (PARTS, N, D)), _stack(17, (PARTS, D)),
+        _stack(18, (PARTS, D)),
     )
-    if out is not None:
-        ref = _k.digest_tables_batched_pallas(parts, agg, z, interpret=True)
-        for got, want in zip(out, ref):
-            np.testing.assert_allclose(got, np.asarray(want), atol=1e-4)
 
 
 @pytest.mark.parametrize("tau", [0.0, 1.0])
-def test_digest_tables_rows_lowers_natively(tau):
+def test_digest_tables_rows_lowers_natively(one_chip, tau):
     """The sampled-digest audit kernel: one HBM pass over only the k
     sampled partitions, their ids scalar-prefetched into SMEM to steer the
     grid — the dynamic-index block maps are exactly what interpret mode
     cannot validate. tau=0 is the verified:* digest, tau>0 the
     ButterflyClip clip-weighted variant."""
-    k = 2
-    parts = _stack(27, (PARTS, N, D))
-    agg = _stack(28, (PARTS, D))
-    z = _stack(29, (PARTS, D))
-    rows = jnp.asarray([3, 1], jnp.int32)
 
     def fn(p, a, zz, r):
         return _k.digest_tables_rows_pallas(
             p, a, zz, r, tau, interpret=False
         )
 
-    out = _validate(fn, parts, agg, z, rows)
-    if out is not None:
-        ref = _k.digest_tables_rows_pallas(
-            parts, agg, z, rows, tau, interpret=True
-        )
-        for got, want in zip(out, ref):
-            assert got.shape == (k, N)
-            np.testing.assert_allclose(got, np.asarray(want), atol=1e-4)
+    compiled = _validate(
+        one_chip, fn, _stack(27, (PARTS, N, D)), _stack(28, (PARTS, D)),
+        _stack(29, (PARTS, D)), jnp.asarray([3, 1], jnp.int32),
+    )
+    s_shape = compiled.out_info[0].shape
+    assert s_shape == (2, N)
 
 
 @pytest.mark.parametrize("weighted", [False, True])
-def test_mean_digest_fused_lowers_natively(weighted):
+def test_mean_digest_fused_lowers_natively(one_chip, weighted):
     """verified:mean's fused aggregation + digest-epilogue kernel (2 HBM
     passes, two grid phases sharing the aggregate output ref) must lower
     as a unit."""
-    parts = _stack(19, (PARTS, N, D))
-    z = _stack(20, (PARTS, D))
     w = jnp.ones((N,)).at[1].set(0.0) if weighted else None
 
     def fn(p, zz):
         return _k.mean_digest_fused_pallas(p, zz, w, interpret=False)
 
-    out = _validate(fn, parts, z)
-    if out is not None:
-        ref = _k.mean_digest_fused_pallas(parts, z, w, interpret=True)
-        for got, want in zip(out, ref):
-            np.testing.assert_allclose(got, np.asarray(want), atol=1e-4)
+    _validate(one_chip, fn, _stack(19, (PARTS, N, D)), _stack(20, (PARTS, D)))
 
 
 @pytest.mark.parametrize("base", ["mean", "coordinate_median"])
-def test_verified_wrapped_spec_dispatch_lowers(base):
+def test_verified_wrapped_spec_dispatch_lowers(one_chip, base):
     """The verified:* route into the digest kernels: verified_aggregate on
-    a wrapped spec with use_pallas=True must reach the fused mean-digest
+    a wrapped spec with use_pallas=True reaches the fused mean-digest
     kernel (verified:mean) / the standalone digest kernel (the sort-based
-    bases) through spec dispatch. Under REPRO_PALLAS_COMPILE=1 this lowers
-    natively; in interpret mode it doubles as a spec-vs-jnp equivalence
-    check."""
+    bases) through spec dispatch. It compiles natively for the chip, and
+    on the CPU the interpreted kernels match the jnp path."""
     from repro.core.aggregators import AggregatorSpec, verified_aggregate
-    from repro.kernels import ops
 
     n, d = N, N * D
     g = _stack(21, (n, d))
@@ -240,66 +195,45 @@ def test_verified_wrapped_spec_dispatch_lowers(base):
         )
         return agg, s, norms, iters
 
-    if ops._INTERPRET:
-        got = jax.jit(fn)(g, z)
-        ref = verified_aggregate(spec, g, z, use_pallas=False)
-        want = (ref[0], ref[2], ref[3])
-        for a, b in zip(got[:3], want):
-            np.testing.assert_allclose(
-                np.asarray(a), np.asarray(b), atol=1e-4
-            )
-    else:
-        _validate(fn, g, z)
+    _validate(one_chip, fn, g, z)
+    got = jax.jit(fn)(g, z)
+    ref = verified_aggregate(spec, g, z, use_pallas=False)
+    for a, b in zip(got[:3], (ref[0], ref[2], ref[3])):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4)
 
 
-def test_repro_pallas_compile_env_flag():
-    """REPRO_PALLAS_COMPILE=1 must flip the ops layer to interpret=False and
-    the resulting jaxpr must still Mosaic-lower (subprocess: the flag is
-    read at import)."""
-    if _export_fn() is None and not _on_tpu():
-        pytest.skip("no TPU and no cross-platform export API in this jax")
-    code = """
-import jax, jax.numpy as jnp
-import repro.kernels.ops as ops
-assert ops._INTERPRET is False, "REPRO_PALLAS_COMPILE=1 not honoured"
-parts = jnp.ones((4, 8, 384), jnp.float32)
-z = jnp.ones((4, 384), jnp.float32)
-fn = jax.jit(lambda p, z: ops.butterfly_clip_fused_op(p, 1.0, z, n_iters=3))
-if jax.default_backend() == "tpu":
-    jax.block_until_ready(fn(parts, z))
-else:
-    try:
-        from jax import export as exp
-        exporter = exp.export
-    except ImportError:
-        from jax._src.export import _export as exp
-        exporter = exp.export
-    module = exporter(fn, platforms=["tpu"])(parts, z).mlir_module()
-    assert "tpu_custom_call" in module
-print("PALLAS_COMPILE_OK")
-"""
-    env = dict(os.environ)
-    env["REPRO_PALLAS_COMPILE"] = "1"
-    env["PYTHONPATH"] = os.path.join(REPO, "src")
-    r = subprocess.run(
-        [sys.executable, "-W", "ignore", "-c", code],
-        capture_output=True, text=True, env=env, timeout=300,
-    )
-    assert r.returncode == 0, r.stdout[-2000:] + "\n---\n" + r.stderr[-2000:]
-    assert "PALLAS_COMPILE_OK" in r.stdout
+def test_ops_interpret_on_cpu_and_refuse_other_platforms():
+    """The ops layer picks the kernel mode from the platform it is lowered
+    for: the Pallas interpreter for the CPU (no Mosaic kernel in the
+    program, results equal to an explicit interpret=True call), the native
+    kernel for a TPU, and an error for any other platform."""
+    from repro.kernels import ops
+
+    parts = _stack(30, (PARTS, N, D))
+    z = _stack(31, (PARTS, D))
+    fn = jax.jit(lambda p, zz: ops.butterfly_clip_fused_op(
+        p, 1.0, zz, n_iters=3))
+    assert "tpu_custom_call" not in fn.lower(parts, z).as_text()
+    got = fn(parts, z)
+    want = _k.butterfly_clip_fused_pallas(
+        parts, jnp.full((3,), 1.0, jnp.float32), z, interpret=True)
+    np.testing.assert_array_equal(np.asarray(got[0]), np.asarray(want[0]))
+    np.testing.assert_array_equal(np.asarray(got[1]), np.asarray(want[1]).T)
+
+    tpu = jax.export.export(fn, platforms=["tpu"])(parts, z)
+    assert "tpu_custom_call" in tpu.mlir_module()
+    with pytest.raises(NotImplementedError, match="platform"):
+        jax.export.export(fn, platforms=["cuda"])(parts, z)
 
 
 @pytest.mark.parametrize("codec", ["int8", "bf16"])
-def test_fused_dequant_butterfly_lowers_natively(codec):
+def test_fused_dequant_butterfly_lowers_natively(one_chip, codec):
     """The compressed:butterfly_clip hot path — fused dequantize + clip +
     digest over WIRE payloads (int8/bf16 blocks in HBM, f32 sidecar scales
-    in a (1, n, 1) block) — through the real Mosaic pipeline, per wire
-    dtype."""
+    in a (1, n, 1) block), per wire dtype."""
     from repro.core import compression as comp
 
-    x = _stack(23, (PARTS, N, D))
-    qs, scales = comp.quantize(x, codec)
-    z = _stack(24, (PARTS, D))
+    qs, scales = comp.quantize(_stack(23, (PARTS, N, D)), codec)
     taus = jnp.full((ITERS,), 1.0, jnp.float32)
 
     def fn(q, s, zz):
@@ -307,42 +241,60 @@ def test_fused_dequant_butterfly_lowers_natively(codec):
             q, s, taus, zz, interpret=False
         )
 
-    out = _validate(fn, qs, scales, z)
-    if out is not None:
-        ref = _k.butterfly_clip_fused_dequant_pallas(
-            qs, scales, taus, z, interpret=True
-        )
-        for got, want in zip(out, ref):
-            np.testing.assert_allclose(got, np.asarray(want), atol=1e-4)
+    _validate(one_chip, fn, qs, scales, _stack(24, (PARTS, D)))
 
 
 @pytest.mark.parametrize("codec", ["int8", "bf16"])
-def test_mean_digest_fused_dequant_lowers_natively(codec):
+def test_mean_digest_fused_dequant_lowers_natively(one_chip, codec):
     """compressed:verified:mean's fused dequantize + mean + digest kernel
     must lower as a unit for both wire dtypes (the int8 path exercises
     integer-block loads that interpret mode cannot validate)."""
     from repro.core import compression as comp
 
-    x = _stack(25, (PARTS, N, D))
-    qs, scales = comp.quantize(x, codec)
-    z = _stack(26, (PARTS, D))
+    qs, scales = comp.quantize(_stack(25, (PARTS, N, D)), codec)
     w = jnp.ones((N,)).at[2].set(0.0)
 
     def fn(q, s, zz):
-        return _k.mean_digest_fused_dequant_pallas(q, s, zz, w, interpret=False)
+        return _k.mean_digest_fused_dequant_pallas(q, s, zz, w,
+                                                   interpret=False)
 
-    out = _validate(fn, qs, scales, z)
-    if out is not None:
-        ref = _k.mean_digest_fused_dequant_pallas(
-            qs, scales, z, w, interpret=True
-        )
-        for got, want in zip(out, ref):
-            np.testing.assert_allclose(got, np.asarray(want), atol=1e-4)
+    _validate(one_chip, fn, qs, scales, _stack(26, (PARTS, D)))
 
 
-def test_adaptive_step_kernel_lowers_natively():
+@pytest.mark.parametrize("kernel", [
+    "butterfly_clip_fused", "butterfly_clip_fused_dequant",
+    "mean_digest_fused_dequant",
+])
+def test_kernel_compiles_at_albert_engine_width(one_chip, kernel):
+    """The engine's partition stack at albert-large's full width: 4 owner
+    partitions of 4 peers x d/4 coordinates (1.25 GB of f32, 313 MB of
+    int8 wire words). The flagship fused kernel and the compressed int8
+    kernels at the size the chip runs, not only at test sizes."""
+    part = ALBERT_D // ENGINE_PEERS
+    p, n = ENGINE_PEERS, ENGINE_PEERS
+    f32 = jnp.float32
+    z = jax.ShapeDtypeStruct((p, part), f32)
+    taus = jnp.full((ITERS,), 1.0, f32)
+    if kernel == "butterfly_clip_fused":
+        args = (jax.ShapeDtypeStruct((p, n, part), f32), z)
+        fn = lambda x, zz: _k.butterfly_clip_fused_pallas(
+            x, taus, zz, interpret=False)
+    else:
+        q = jax.ShapeDtypeStruct((p, n, part), jnp.int8)
+        scales = jax.ShapeDtypeStruct((p, n), f32)
+        args = (q, scales, z)
+        if kernel == "butterfly_clip_fused_dequant":
+            fn = lambda x, s, zz: _k.butterfly_clip_fused_dequant_pallas(
+                x, s, taus, zz, interpret=False)
+        else:
+            fn = lambda x, s, zz: _k.mean_digest_fused_dequant_pallas(
+                x, s, zz, interpret=False)
+    _validate(one_chip, fn, *args)
+
+
+def test_adaptive_step_kernel_lowers_natively(one_chip):
     """The one-pass adaptive clip iteration (cw from carried sq, v update,
-    incremental next-sq) through the real Mosaic pipeline."""
+    incremental next-sq)."""
     parts = _stack(10, (PARTS, N, D))
     v = _stack(11, (PARTS, 1, D)) * 0.1
     sq = jnp.sum((parts - v) ** 2, axis=-1, keepdims=True)
@@ -350,22 +302,17 @@ def test_adaptive_step_kernel_lowers_natively():
     def fn(p, vv, ss):
         return _k.adaptive_clip_step_pallas(p, vv, ss, 1.0, interpret=False)
 
-    out = _validate(fn, parts, v, sq)
-    if out is not None:
-        ref = _k.adaptive_clip_step_pallas(parts, v, sq, 1.0, interpret=True)
-        for got, want in zip(out, ref):
-            np.testing.assert_allclose(got, np.asarray(want), atol=1e-4)
+    _validate(one_chip, fn, parts, v, sq)
 
 
 @pytest.mark.parametrize("adaptive", [False, True])
-def test_spec_dispatched_fused_kernels_lower(adaptive):
+def test_spec_dispatched_fused_kernels_lower(one_chip, adaptive):
     """The AggregatorSpec route into the fused kernels: verified_aggregate
-    (the engine's aggregation phase) with use_pallas=True must reach the
-    fused / adaptive Mosaic kernels through spec dispatch. Under
-    REPRO_PALLAS_COMPILE=1 (the CI Mosaic job) this lowers natively; in
-    interpret mode it doubles as a spec-vs-jnp equivalence check."""
+    (the engine's aggregation phase) with use_pallas=True reaches the
+    fused / adaptive Mosaic kernels through spec dispatch. It compiles
+    natively for the chip, and on the CPU the interpreted kernels match
+    the jnp path."""
     from repro.core.aggregators import AggregatorSpec, verified_aggregate
-    from repro.kernels import ops
 
     n, d = 8, 8 * D
     g = _stack(14, (n, d))
@@ -380,24 +327,18 @@ def test_spec_dispatched_fused_kernels_lower(adaptive):
         )
         return agg, s, norms, iters
 
-    if ops._INTERPRET:
-        got = jax.jit(fn)(g, z)
-        ref = verified_aggregate(spec, g, z, use_pallas=False)
-        want = (ref[0], ref[2], ref[3], ref[4])
-        for a, b in zip(got[:3], want[:3]):
-            np.testing.assert_allclose(
-                np.asarray(a), np.asarray(b), atol=1e-4
-            )
-    else:
-        _validate(fn, g, z)
+    _validate(one_chip, fn, g, z)
+    got = jax.jit(fn)(g, z)
+    ref = verified_aggregate(spec, g, z, use_pallas=False)
+    for a, b in zip(got[:3], (ref[0], ref[2], ref[3])):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4)
 
 
 @pytest.mark.parametrize("warm", [False, True])
-def test_adaptive_driver_lowers_natively(warm):
+def test_adaptive_driver_lowers_natively(one_chip, warm):
     """The full early-exit driver: lax.while_loop wrapped around the Mosaic
     step kernel must lower as a unit (early-exit kernels cannot merge
-    interpreter-only — this is the CI gate for the adaptive family)."""
-    parts = _stack(12, (PARTS, N, D))
+    interpreter-only)."""
     v0 = _stack(13, (PARTS, D)) * 0.1 if warm else None
 
     def fn(p):
@@ -405,10 +346,4 @@ def test_adaptive_driver_lowers_natively(warm):
             p, 1.0, 1e-4, ITERS, v0=v0, interpret=False
         )
 
-    out = _validate(fn, parts)
-    if out is not None:
-        ref = _k.butterfly_clip_adaptive_pallas(
-            parts, 1.0, 1e-4, ITERS, v0=v0, interpret=True
-        )
-        np.testing.assert_allclose(out[0], np.asarray(ref[0]), atol=1e-4)
-        np.testing.assert_array_equal(out[1], np.asarray(ref[1]))
+    _validate(one_chip, fn, _stack(12, (PARTS, N, D)))

@@ -28,12 +28,13 @@ def test_btard_step_equals_baseline_when_honest():
     out = _run(
         """
         import jax, jax.numpy as jnp
+        from repro.launch.mesh import make_mesh
         from repro.launch.steps import make_baseline_train_step, make_btard_train_step
         from repro.models import get_model
         from repro.optim import sgd
         from repro.configs.base import InputShape
 
-        mesh = jax.make_mesh((4, 2), ('data', 'model'))
+        mesh = make_mesh((4, 2), ('data', 'model'))
         m = get_model('qwen3-1.7b', reduced=True)
         shape = InputShape('t', 64, 8, 'train')
         opt = sgd(0.05)
@@ -57,12 +58,13 @@ def test_device_attack_detected_and_clipped():
     out = _run(
         """
         import jax, jax.numpy as jnp
+        from repro.launch.mesh import make_mesh
         from repro.launch.steps import make_btard_train_step
         from repro.models import get_model
         from repro.optim import sgd
         from repro.configs.base import InputShape
 
-        mesh = jax.make_mesh((4, 2), ('data', 'model'))
+        mesh = make_mesh((4, 2), ('data', 'model'))
         m = get_model('qwen3-1.7b', reduced=True)
         shape = InputShape('t', 64, 8, 'train')
         opt = sgd(0.05)
@@ -92,12 +94,13 @@ def test_multi_pod_mesh_axes():
     out = _run(
         """
         import jax, jax.numpy as jnp
+        from repro.launch.mesh import make_mesh
         from repro.launch.steps import make_btard_train_step
         from repro.models import get_model
         from repro.optim import sgd
         from repro.configs.base import InputShape
 
-        mesh = jax.make_mesh((2, 2, 2), ('pod', 'data', 'model'))
+        mesh = make_mesh((2, 2, 2), ('pod', 'data', 'model'))
         m = get_model('qwen3-1.7b', reduced=True)
         shape = InputShape('t', 64, 8, 'train')
         opt = sgd(0.05)
@@ -118,12 +121,13 @@ def test_scan_step_equals_stepwise_and_warm_start_runs():
     out = _run(
         """
         import jax, jax.numpy as jnp
+        from repro.launch.mesh import make_mesh
         from repro.launch.steps import make_btard_scan_train_step, make_btard_train_step
         from repro.models import get_model
         from repro.optim import sgd
         from repro.configs.base import InputShape
 
-        mesh = jax.make_mesh((4, 2), ('data', 'model'))
+        mesh = make_mesh((4, 2), ('data', 'model'))
         m = get_model('qwen3-1.7b', reduced=True)
         shape = InputShape('t', 64, 8, 'train')
         opt = sgd(0.05)
@@ -167,12 +171,13 @@ def test_pallas_kernel_inside_distributed_step():
     out = _run(
         """
         import jax, jax.numpy as jnp
+        from repro.launch.mesh import make_mesh
         from repro.launch.steps import make_btard_train_step
         from repro.models import get_model
         from repro.optim import sgd
         from repro.configs.base import InputShape
 
-        mesh = jax.make_mesh((4, 2), ('data', 'model'))
+        mesh = make_mesh((4, 2), ('data', 'model'))
         m = get_model('qwen3-1.7b', reduced=True)
         shape = InputShape('t', 64, 8, 'train')
         opt = sgd(0.05)

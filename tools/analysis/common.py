@@ -13,7 +13,7 @@ from __future__ import annotations
 from dataclasses import dataclass, field
 
 import jax
-from jax import core as jcore
+from jax.extend import core as jcore
 
 # Primitives that reach outside the traced program. Any of these inside a
 # protocol phase breaks bitwise recomputability: a validator re-running the

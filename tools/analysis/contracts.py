@@ -29,7 +29,7 @@ import time
 
 import jax
 import jax.numpy as jnp
-from jax import core as jcore
+from jax.extend import core as jcore
 
 from tools.analysis.common import CheckResult, Finding
 

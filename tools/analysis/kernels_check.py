@@ -25,6 +25,7 @@ import time
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
 
 from tools.analysis.common import CheckResult, Finding, iter_eqns
 
@@ -191,11 +192,14 @@ def block_spec_findings(closed, where: str):
             continue
         gm = e.params["grid_mapping"]
         for bm in gm.block_mappings:
-            arr = bm.array_shape_dtype
-            dims = [s for s in bm.block_shape if isinstance(s, int)]
+            arr = bm.array_aval
+            # tiled dims (pl.Blocked); squeezed dims carry no block size
+            dims = [s.block_size for s in bm.block_shape
+                    if isinstance(s, pl.Blocked)]
             if not dims:
                 continue
-            space = str(getattr(bm.block_aval, "memory_space", None) or "")
+            space = str(getattr(bm.transformed_block_aval, "memory_space",
+                                None) or "")
             origin = f"{where}:{bm.origin}"
             if all(s == 1 for s in dims):
                 if "smem" not in space.lower():

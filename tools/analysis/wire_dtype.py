@@ -29,8 +29,7 @@ import time
 
 import jax
 import jax.numpy as jnp
-from jax import core as jcore
-from jax.experimental.shard_map import shard_map
+from jax.extend import core as jcore
 from jax.sharding import AbstractMesh, PartitionSpec as P
 
 from tools.analysis.common import (
@@ -120,7 +119,7 @@ def trace_aggregation_stage(spec: str, *, groups=None, audit_k=None,
     """
     from repro.launch.steps import aggregation_stage
 
-    mesh = AbstractMesh((("peers", N_PEERS),))
+    mesh = AbstractMesh((N_PEERS,), ("peers",))
     hier = groups is not None and groups > 1
 
     def region(g_vec, weights, seed, byz_mask, v0_full):
@@ -136,11 +135,11 @@ def trace_aggregation_stage(spec: str, *, groups=None, audit_k=None,
     verif_specs = {k: P("peers") for k in _VERIF_KEYS}
     verif_specs["s_table"] = P("peers", None) if hier else P(None, None)
     verif_specs["norm_table"] = verif_specs["s_table"]
-    f = shard_map(
+    f = jax.shard_map(
         region, mesh=mesh,
         in_specs=(P("peers"), P(), P(), P(), P()),
         out_specs=(P(), verif_specs),
-        check_rep=False,
+        check_vma=False,
     )
     args = (
         jax.ShapeDtypeStruct((N_PEERS * d,), jnp.bfloat16),
