@@ -12,6 +12,8 @@ shapes static — recorded in DESIGN.md).
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -288,6 +290,8 @@ def delta_max_votes(norms, weights, delta_max):
     return votes, votes > active / 2.0
 
 
+@functools.partial(jax.profiler.annotate_function,
+                   name="btard.host.checksum")
 def checksum_offender_peers(checksums, rel: float = 1e-2):
     """Map violated Verification-2 checksums to aggregator peer ids.
 

@@ -40,8 +40,10 @@ Three call surfaces share the rule:
 """
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -50,6 +52,10 @@ SLOT_VACANT = 0
 SLOT_PROBATION = 1
 SLOT_ACTIVE = 2
 SLOT_BANNED = 3
+
+# a span on the profiler's clock around each chunk-boundary transition
+_membership_span = functools.partial(
+    jax.profiler.annotate_function, name="btard.host.membership")
 
 LIFECYCLE_NAMES = {
     SLOT_VACANT: "vacant",
@@ -153,6 +159,7 @@ class HostMembership:
         return sorted(np.nonzero(self.lifecycle == SLOT_BANNED)[0].tolist())
 
     # -- transitions ------------------------------------------------------
+    @_membership_span
     def apply_events(self, step: int):
         """Fire every scheduled join/leave with event.step == step."""
         for ev in self.events:
@@ -188,6 +195,7 @@ class HostMembership:
             else:
                 raise ValueError(f"unknown membership event kind {ev.kind!r}")
 
+    @_membership_span
     def ban_slots(self, slots, step: int):
         """Ban the current OCCUPANTS of ``slots`` (identity-keyed)."""
         newly = []
@@ -205,6 +213,7 @@ class HostMembership:
             )
         return [s for s, _ in newly]
 
+    @_membership_span
     def observe_probe(self, probe_row, step: int, tol: float = 1e-6):
         """One step's probation spot-check results: ``probe_row`` is the
         per-slot max deviation between the broadcast payload and the

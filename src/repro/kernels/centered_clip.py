@@ -77,13 +77,17 @@ from jax.experimental.pallas import tpu as pltpu
 DEFAULT_BLOCK = 512
 
 
-def _pallas_call(kernel, *, interpret=None, **kwargs):
+def _pallas_call(kernel, *, name, interpret=None, **kwargs):
     """``pl.pallas_call`` that runs natively on a TPU and in the Pallas
     interpreter on the CPU. The platform is the one the call is lowered
     for (``lax.platform_dependent``), read then and not at import; lowering
     for any other platform raises. ``interpret=True``/``False`` forces one
     mode (the native-lowering tests compile ``False`` for a described TPU).
+    ``name`` is the kernel's stable name: the TPU custom call's
+    ``kernel_name`` and, in the interpreter, a scope of its ops, so that a
+    trace finds the kernel's device time by it.
     """
+    kwargs["name"] = name
     if interpret is not None:
         return pl.pallas_call(kernel, interpret=interpret, **kwargs)
     native = pl.pallas_call(kernel, interpret=False, **kwargs)
@@ -179,6 +183,7 @@ def centered_clip_pallas(
 
     out = _pallas_call(
         _cc_kernel,
+        name="centered_clip",
         grid=(n_iters, 2, n_blocks),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
@@ -271,6 +276,7 @@ def butterfly_clip_pallas(
 
     out = _pallas_call(
         _bcc_kernel,
+        name="butterfly_clip",
         grid=(n_parts, n_iters, 2, n_blocks),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
@@ -447,6 +453,7 @@ def centered_clip_fused_pallas(
 
     out, s, norms = _pallas_call(
         functools.partial(_fused_body, False),
+        name="cc_fused",
         grid=(n_iters + 2, n_blocks),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
@@ -514,6 +521,7 @@ def butterfly_clip_fused_pallas(
 
     out, s, norms = _pallas_call(
         functools.partial(_fused_body, True),
+        name="butterfly_clip_fused",
         grid=(n_parts, n_iters + 2, n_blocks),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
@@ -592,6 +600,7 @@ def butterfly_clip_fused_dequant_pallas(
 
     out, s, norms = _pallas_call(
         functools.partial(_fused_dequant_body, True),
+        name="butterfly_clip_fused_dequant",
         grid=(n_parts, n_iters + 2, n_blocks),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
@@ -702,6 +711,7 @@ def adaptive_clip_step_pallas(
     w2 = weights.reshape(n, 1).astype(jnp.float32)
     return _pallas_call(
         _adaptive_step_kernel,
+        name="cc_adaptive_step",
         grid=(n_parts, n_blocks),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
@@ -832,6 +842,7 @@ def verify_tables_pallas(
     tau2 = jnp.asarray(tau, jnp.float32).reshape(1, 1)
     s, norms = _pallas_call(
         _vt_kernel,
+        name="verify_tables",
         grid=(n_blocks,),
         in_specs=[
             # scalar: whole (1, 1) array in SMEM — a (1, 1) VMEM block is
@@ -934,6 +945,7 @@ def digest_tables_batched_pallas(
 
     s, norms = _pallas_call(
         _dg_batched_kernel,
+        name="digest_tables_batched",
         grid=(n_parts, n_blocks),
         in_specs=[
             pl.BlockSpec((1, n, blk), lambda p, b: (p, 0, b)),
@@ -1037,6 +1049,7 @@ def digest_tables_rows_pallas(
     )
     s, norms = _pallas_call(
         _rows_digest_kernel,
+        name="digest_tables_rows",
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((k, 1, n), jnp.float32),
@@ -1118,6 +1131,7 @@ def mean_digest_fused_pallas(
     w2 = weights.reshape(n, 1).astype(jnp.float32)
     agg, s, norms = _pallas_call(
         _md_kernel,
+        name="mean_digest_fused",
         grid=(n_parts, n_blocks),
         in_specs=[
             pl.BlockSpec((n, 1), lambda p, b: (0, 0)),
@@ -1181,6 +1195,7 @@ def mean_digest_fused_dequant_pallas(
     sc3 = scales.reshape(n_parts, n, 1).astype(jnp.float32)
     agg, s, norms = _pallas_call(
         _md_dequant_kernel,
+        name="mean_digest_fused_dequant",
         grid=(n_parts, n_blocks),
         in_specs=[
             pl.BlockSpec((n, 1), lambda p, b: (0, 0)),
@@ -1228,6 +1243,7 @@ def verify_tables_batched_pallas(
     tau2 = jnp.asarray(tau, jnp.float32).reshape(1, 1)
     s, norms = _pallas_call(
         _vt_batched_kernel,
+        name="verify_tables_batched",
         grid=(n_parts, n_blocks),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),

@@ -233,30 +233,33 @@ def aggregation_stage(
     spec = resolve_spec(spec)
     d = g_vec.shape[0]
     if not spec.verifiable:
-        stack = jax.lax.all_gather(g_vec, peer_axes)  # (n_peers, d) each
-        v0 = None
-        if v0_full is not None and spec.warm_startable:
-            v0 = v0_full.astype(jnp.float32)
         join = tuple(gather_axes) if not spec.coordinatewise else ()
-        if join:
-            stack = jax.lax.all_gather(stack, join, axis=1, tiled=True)
-            if v0 is not None:
-                v0 = jax.lax.all_gather(v0, join, axis=0, tiled=True)
-        # pin the gathered transport dtype before the f32 upcast below —
-        # same hoist hazard as the butterfly barrier at the all_to_all
-        stack = jax.lax.optimization_barrier(stack)
-        agg_fn = spec.build(n_peers, stack.shape[1], use_pallas=use_pallas)
-        flat, info = agg_fn(
-            stack.astype(jnp.float32),
-            weights if spec.weighted else None,
-            v0,
-            jax.random.key(seed),
-        )
+        with jax.named_scope("exchange"):
+            stack = jax.lax.all_gather(g_vec, peer_axes)  # (n_peers, d) each
+            v0 = None
+            if v0_full is not None and spec.warm_startable:
+                v0 = v0_full.astype(jnp.float32)
+            if join:
+                stack = jax.lax.all_gather(stack, join, axis=1, tiled=True)
+                if v0 is not None:
+                    v0 = jax.lax.all_gather(v0, join, axis=0, tiled=True)
+            # pin the gathered transport dtype before the f32 upcast below —
+            # same hoist hazard as the butterfly barrier at the all_to_all
+            stack = jax.lax.optimization_barrier(stack)
+        with jax.named_scope("clip"):
+            agg_fn = spec.build(n_peers, stack.shape[1], use_pallas=use_pallas)
+            flat, info = agg_fn(
+                stack.astype(jnp.float32),
+                weights if spec.weighted else None,
+                v0,
+                jax.random.key(seed),
+            )
         if join:  # slice this device's model shard back out
-            my = jnp.zeros((), jnp.int32)
-            for a in join:  # row-major over the joined axes == gather order
-                my = my * jax.lax.psum(1, a) + jax.lax.axis_index(a)
-            flat = jax.lax.dynamic_slice_in_dim(flat, my * d, d)
+            with jax.named_scope("gather"):
+                my = jnp.zeros((), jnp.int32)
+                for a in join:  # row-major over the joined axes == gather order
+                    my = my * jax.lax.psum(1, a) + jax.lax.axis_index(a)
+                flat = jax.lax.dynamic_slice_in_dim(flat, my * d, d)
         verif = {
             "checksum": jnp.zeros((1,), jnp.float32),
             "votes": jnp.zeros((1,), jnp.float32),
@@ -294,60 +297,65 @@ def aggregation_stage(
 
     part = -(-d // n_loc)
     pad = part * n_loc - d
-    if pad:
-        g_vec = jnp.concatenate([g_vec, jnp.zeros((pad,), g_vec.dtype)])
-    x = g_vec.reshape(n_loc, part)
-    # each peer receives everyone's copy of ITS partition. The barrier pins
-    # the transport dtype: without it XLA hoists the downstream f32 upcast
-    # ahead of the collective, silently undoing bf16 transport (§Perf H3)
-    # — or, for compressed specs, the wire codec itself.
-    comp_wire = None
-    if comp_mod.is_wrapped(spec):
-        # compressed:* — quantize each (peer -> owner) payload BEFORE the
-        # exchange: the gradient all_to_all ships 1-2 byte wire words, plus
-        # ONE f32 sidecar scalar per payload in a second tiny all_to_all
-        # (n_peers floats vs part*n_peers wire words). Every digest below
-        # runs over the DEQUANTIZED wire values (core.compression), so the
-        # owner's tables match any validator's recompute bit-for-bit and
-        # rounding can never trip an accusation.
-        codec = comp_mod.codec_of(spec)
-        wire, scales = comp_mod.quantize(x, codec)  # (n, part), (n,) f32
-        recv_w = jax.lax.all_to_all(
-            wire, peer_axes, split_axis=0, concat_axis=0, tiled=True,
-            axis_index_groups=lvl1_groups,
-        )
-        recv_s = jax.lax.all_to_all(
-            scales, peer_axes, split_axis=0, concat_axis=0, tiled=True,
-            axis_index_groups=lvl1_groups,
-        )
-        recv_w, recv_s = jax.lax.optimization_barrier((recv_w, recv_s))
-        comp_wire = (recv_w, recv_s)
-        recv = comp_mod.dequantize(recv_w, recv_s)  # the f32 wire values
-        spec = comp_mod.inner_spec(spec)  # dispatch below is by inner spec
-    else:
-        recv = jax.lax.all_to_all(
-            x, peer_axes, split_axis=0, concat_axis=0, tiled=True,
-            axis_index_groups=lvl1_groups,
-        )
-        recv = jax.lax.optimization_barrier(recv)
+    with jax.named_scope("exchange"):
+        if pad:
+            g_vec = jnp.concatenate([g_vec, jnp.zeros((pad,), g_vec.dtype)])
+        x = g_vec.reshape(n_loc, part)
+        # each peer receives everyone's copy of ITS partition. The barrier pins
+        # the transport dtype: without it XLA hoists the downstream f32 upcast
+        # ahead of the collective, silently undoing bf16 transport (§Perf H3)
+        # — or, for compressed specs, the wire codec itself.
+        comp_wire = None
+        if comp_mod.is_wrapped(spec):
+            # compressed:* — quantize each (peer -> owner) payload BEFORE the
+            # exchange: the gradient all_to_all ships 1-2 byte wire words, plus
+            # ONE f32 sidecar scalar per payload in a second tiny all_to_all
+            # (n_peers floats vs part*n_peers wire words). Every digest below
+            # runs over the DEQUANTIZED wire values (core.compression), so the
+            # owner's tables match any validator's recompute bit-for-bit and
+            # rounding can never trip an accusation.
+            codec = comp_mod.codec_of(spec)
+            wire, scales = comp_mod.quantize(x, codec)  # (n, part), (n,) f32
+            recv_w = jax.lax.all_to_all(
+                wire, peer_axes, split_axis=0, concat_axis=0, tiled=True,
+                axis_index_groups=lvl1_groups,
+            )
+            recv_s = jax.lax.all_to_all(
+                scales, peer_axes, split_axis=0, concat_axis=0, tiled=True,
+                axis_index_groups=lvl1_groups,
+            )
+            recv_w, recv_s = jax.lax.optimization_barrier((recv_w, recv_s))
+            comp_wire = (recv_w, recv_s)
+            recv = comp_mod.dequantize(recv_w, recv_s)  # the f32 wire values
+            spec = comp_mod.inner_spec(spec)  # dispatch below is by inner spec
+        else:
+            recv = jax.lax.all_to_all(
+                x, peer_axes, split_axis=0, concat_axis=0, tiled=True,
+                axis_index_groups=lvl1_groups,
+            )
+            recv = jax.lax.optimization_barrier(recv)
 
     # --- z for the verification tables (Alg. 6): derived from the shared
     # MPRNG seed, folded by partition owner index; commitments are host-side
     # (protocol). Known before the aggregation runs, so the fused kernel can
     # emit the tables from its epilogue pass. Hierarchical mode folds by
     # MEMBER index: z is shared across groups (core.hierarchy's z1).
-    z = jax.random.normal(jax.random.fold_in(jax.random.key(seed), fold_idx), (part,))
-    z = z / jnp.maximum(jnp.linalg.norm(z), 1e-30)
+    with jax.named_scope("verify"):
+        z = jax.random.normal(
+            jax.random.fold_in(jax.random.key(seed), fold_idx), (part,))
+        z = z / jnp.maximum(jnp.linalg.norm(z), 1e-30)
 
     if verif_mod.is_wrapped(spec):
         # wrapped coordinatewise spec: the partition owner runs the BASE fn
         # over its all_to_all'd stack (exact — coordinatewise fns decompose
         # over the partition split) and broadcasts the generalized digests;
-        # the fused-vs-standalone kernel dispatch lives in owner_aggregate.
-        agg, s_local, norms_local, iters_used = verif_mod.owner_aggregate(
-            spec, recv, z, weights, use_pallas=use_pallas,
-            key=jax.random.key(seed), wire=comp_wire,
-        )
+        # the fused-vs-standalone kernel dispatch lives in owner_aggregate,
+        # so its digests count under ``clip`` with the aggregation.
+        with jax.named_scope("clip"):
+            agg, s_local, norms_local, iters_used = verif_mod.owner_aggregate(
+                spec, recv, z, weights, use_pallas=use_pallas,
+                key=jax.random.key(seed), wire=comp_wire,
+            )
         tau_v = 0.0
         with_checksum = verif_mod.has_zero_checksum(spec)
         return _verify_audit_tail(
@@ -361,13 +369,17 @@ def aggregation_stage(
     tau, clip_iters = p["tau"], p["n_iters"]
     adaptive_tol = p["adaptive_tol"]
 
-    v0 = None
-    if v0_full is not None:
-        if pad:
-            v0_full = jnp.concatenate(
-                [v0_full, jnp.zeros((pad,), v0_full.dtype)]
-            )
-        v0 = v0_full.reshape(n_loc, part)[fold_idx].astype(jnp.float32)
+    # ``clip`` is the aggregation proper; the fused Pallas kernels emit the
+    # digest tables from their epilogue pass, so on those paths the table
+    # work counts under ``clip`` too, and ``verify`` holds only the rest
+    with jax.named_scope("clip"):
+        v0 = None
+        if v0_full is not None:
+            if pad:
+                v0_full = jnp.concatenate(
+                    [v0_full, jnp.zeros((pad,), v0_full.dtype)]
+                )
+            v0 = v0_full.reshape(n_loc, part)[fold_idx].astype(jnp.float32)
 
     iters_used = jnp.asarray(clip_iters, jnp.int32)
     if adaptive_tol is not None and use_pallas:
@@ -375,14 +387,16 @@ def aggregation_stage(
 
         # early-exit one-pass-per-iteration driver (single-partition batch),
         # then ONE verification-table pass against the final iterate
-        agg_b, iters = butterfly_clip_adaptive_op(
-            recv[None], tau, adaptive_tol, weights,
-            v0=None if v0 is None else v0[None], max_iters=clip_iters,
-        )
-        agg, iters_used = agg_b[0], iters[0]
-        s_local, norms_local = verify_tables_op(
-            recv, agg, z.astype(jnp.float32), tau
-        )
+        with jax.named_scope("clip"):
+            agg_b, iters = butterfly_clip_adaptive_op(
+                recv[None], tau, adaptive_tol, weights,
+                v0=None if v0 is None else v0[None], max_iters=clip_iters,
+            )
+            agg, iters_used = agg_b[0], iters[0]
+        with jax.named_scope("verify"):
+            s_local, norms_local = verify_tables_op(
+                recv, agg, z.astype(jnp.float32), tau
+            )
     elif use_pallas and comp_wire is not None:
         from repro.kernels.ops import butterfly_clip_fused_dequant_op
 
@@ -390,32 +404,40 @@ def aggregation_stage(
         # clip+digest kernel makes its n_iters + 2 passes over 1-2 byte
         # data, dequantizing in-register against the sidecar scales
         qs, qscales = comp_wire
-        agg_b, s_b, n_b = butterfly_clip_fused_dequant_op(
-            qs[None], qscales[None], tau, z.astype(jnp.float32)[None],
-            weights, v0=None if v0 is None else v0[None], n_iters=clip_iters,
-        )
-        agg, s_local, norms_local = agg_b[0], s_b[:, 0], n_b[:, 0]
+        with jax.named_scope("clip"):
+            agg_b, s_b, n_b = butterfly_clip_fused_dequant_op(
+                qs[None], qscales[None], tau, z.astype(jnp.float32)[None],
+                weights, v0=None if v0 is None else v0[None],
+                n_iters=clip_iters,
+            )
+            agg, s_local, norms_local = agg_b[0], s_b[:, 0], n_b[:, 0]
     elif use_pallas:
         from repro.kernels.ops import centered_clip_fused_op
 
         # fused one-pass-per-iteration kernel: aggregate + s_i = <z, Delta_i>
         # + ||x_i - v|| in n_iters + 2 HBM passes of the peer stack
-        agg, s_local, norms_local = centered_clip_fused_op(
-            recv, tau, z.astype(jnp.float32), weights, v0=v0, n_iters=clip_iters
-        )
+        with jax.named_scope("clip"):
+            agg, s_local, norms_local = centered_clip_fused_op(
+                recv, tau, z.astype(jnp.float32), weights, v0=v0,
+                n_iters=clip_iters,
+            )
     else:
-        if adaptive_tol is not None:
-            agg, iters_used = centered_clip_adaptive(
-                recv, tau, adaptive_tol, clip_iters, weights=weights, v0=v0
-            )
-        else:
-            agg = centered_clip(
-                recv, tau=tau, n_iters=clip_iters, weights=weights, v0=v0
-            )
-        agg = agg.astype(jnp.float32)
-        deltas = clip_residuals(recv.astype(jnp.float32), agg, tau)
-        s_local = deltas @ z  # (n_peers,) — s_i^{my partition}
-        norms_local = jnp.linalg.norm(recv.astype(jnp.float32) - agg[None], axis=1)
+        with jax.named_scope("clip"):
+            if adaptive_tol is not None:
+                agg, iters_used = centered_clip_adaptive(
+                    recv, tau, adaptive_tol, clip_iters, weights=weights,
+                    v0=v0,
+                )
+            else:
+                agg = centered_clip(
+                    recv, tau=tau, n_iters=clip_iters, weights=weights, v0=v0
+                )
+            agg = agg.astype(jnp.float32)
+        with jax.named_scope("verify"):
+            deltas = clip_residuals(recv.astype(jnp.float32), agg, tau)
+            s_local = deltas @ z  # (n_peers,) — s_i^{my partition}
+            norms_local = jnp.linalg.norm(
+                recv.astype(jnp.float32) - agg[None], axis=1)
 
     return _verify_audit_tail(
         g_vec, d, pad, recv, agg, s_local, norms_local, iters_used, weights,
@@ -434,58 +456,59 @@ def _verify_audit_tail(
     """Shared post-aggregation tail of the verifiable butterfly paths:
     lying-owner simulation, validator audit, sampled-column masking, then
     the table broadcast (:func:`_emit_tables`)."""
-    # --- aggregator-shift attack (the lying owner): the Byzantine owner
-    # corrupts its partition aggregate AFTER aggregating and recomputes its
-    # digests against the corrupted value — self-consistent tables, so the
-    # V1 mismatch rule never fires; detection falls to the V2 checksum
-    # (linear specs) or the validator audit below (any spec).
-    agg_honest = agg
-    if agg_attack_scale is not None and byz_mask is not None:
-        is_byz = byz_mask[my_idx] > 0
-        rms = jnp.linalg.norm(agg) / jnp.sqrt(jnp.float32(agg.shape[0]))
-        agg = jnp.where(is_byz, agg + agg_attack_scale * (rms + 1e-8), agg)
-        diff = recv.astype(jnp.float32) - agg[None]
-        n_att = jnp.linalg.norm(diff, axis=1)
-        dots = diff @ z.astype(jnp.float32)
-        if tau_v > 0:
-            s_att = jnp.minimum(1.0, tau_v / jnp.maximum(n_att, 1e-30)) * dots
-        else:
-            s_att = dots
-        s_local = jnp.where(is_byz, s_att, s_local)
-        norms_local = jnp.where(is_byz, n_att, norms_local)
+    with jax.named_scope("verify"):
+        # --- aggregator-shift attack (the lying owner): the Byzantine owner
+        # corrupts its partition aggregate AFTER aggregating and recomputes its
+        # digests against the corrupted value — self-consistent tables, so the
+        # V1 mismatch rule never fires; detection falls to the V2 checksum
+        # (linear specs) or the validator audit below (any spec).
+        agg_honest = agg
+        if agg_attack_scale is not None and byz_mask is not None:
+            is_byz = byz_mask[my_idx] > 0
+            rms = jnp.linalg.norm(agg) / jnp.sqrt(jnp.float32(agg.shape[0]))
+            agg = jnp.where(is_byz, agg + agg_attack_scale * (rms + 1e-8), agg)
+            diff = recv.astype(jnp.float32) - agg[None]
+            n_att = jnp.linalg.norm(diff, axis=1)
+            dots = diff @ z.astype(jnp.float32)
+            if tau_v > 0:
+                s_att = jnp.minimum(1.0, tau_v / jnp.maximum(n_att, 1e-30)) * dots
+            else:
+                s_att = dots
+            s_local = jnp.where(is_byz, s_att, s_local)
+            norms_local = jnp.where(is_byz, n_att, norms_local)
 
-    # --- validator audit arm (launch-side CHOOSETARGET): the shared seed
-    # elects one owner column per step; validators recompute that column's
-    # aggregation from the same payloads (bit-identical here — agg_honest
-    # IS that recompute) and report the max deviation of the value the
-    # owner actually broadcast. Exact zero for honest owners.
-    t_col = jnp.mod(jnp.asarray(seed, jnp.int32), n_loc)
-    audit_agg = jnp.where(
-        fold_idx == t_col,
-        jnp.max(jnp.abs(agg.astype(jnp.float32)
-                        - agg_honest.astype(jnp.float32))),
-        0.0,
-    )
+        # --- validator audit arm (launch-side CHOOSETARGET): the shared seed
+        # elects one owner column per step; validators recompute that column's
+        # aggregation from the same payloads (bit-identical here — agg_honest
+        # IS that recompute) and report the max deviation of the value the
+        # owner actually broadcast. Exact zero for honest owners.
+        t_col = jnp.mod(jnp.asarray(seed, jnp.int32), n_loc)
+        audit_agg = jnp.where(
+            fold_idx == t_col,
+            jnp.max(jnp.abs(agg.astype(jnp.float32)
+                            - agg_honest.astype(jnp.float32))),
+            0.0,
+        )
 
-    # --- sampled-digest masking: only the audit_k owner columns in this
-    # step's rotating window broadcast digests; everyone else ships zeros.
-    # checksum/votes below are computed FROM the zeroed digests, so the ban
-    # policy is silent at unsampled columns by construction (the
-    # zero-scatter invariant — core.hierarchy).
-    if audit_k is not None:
-        k_tot = min(int(audit_k), n_loc)
-        sampled_me = jnp.mod(fold_idx - jnp.asarray(seed, jnp.int32), n_loc) < k_tot
-        s_local = jnp.where(sampled_me, s_local, 0.0)
-        norms_local = jnp.where(sampled_me, norms_local, 0.0)
+        # --- sampled-digest masking: only the audit_k owner columns in this
+        # step's rotating window broadcast digests; everyone else ships zeros.
+        # checksum/votes below are computed FROM the zeroed digests, so the ban
+        # policy is silent at unsampled columns by construction (the
+        # zero-scatter invariant — core.hierarchy).
+        if audit_k is not None:
+            k_tot = min(int(audit_k), n_loc)
+            sampled_me = jnp.mod(fold_idx - jnp.asarray(seed, jnp.int32), n_loc) < k_tot
+            s_local = jnp.where(sampled_me, s_local, 0.0)
+            norms_local = jnp.where(sampled_me, norms_local, 0.0)
 
-    extra = {
-        "audit_target": jnp.mod(jnp.asarray(seed, jnp.int32), n_peers)[None],
-        "audit_grad_mismatch": (
-            jnp.zeros((1,), jnp.float32) if audit_grad is None
-            else jnp.asarray(audit_grad, jnp.float32)[None]
-        ),
-        "audit_agg_mismatch": jnp.asarray(audit_agg, jnp.float32)[None],
-    }
+        extra = {
+            "audit_target": jnp.mod(jnp.asarray(seed, jnp.int32), n_peers)[None],
+            "audit_grad_mismatch": (
+                jnp.zeros((1,), jnp.float32) if audit_grad is None
+                else jnp.asarray(audit_grad, jnp.float32)[None]
+            ),
+            "audit_agg_mismatch": jnp.asarray(audit_agg, jnp.float32)[None],
+        }
     return _emit_tables(
         g_vec, d, pad, agg, s_local, norms_local, iters_used, weights,
         peer_axes, delta_max, with_checksum=with_checksum,
@@ -511,39 +534,41 @@ def _emit_tables(g_vec, d, pad, agg, s_local, norms_local, iters_used,
     the group aggregates, so the zero-sum checksum identity is exact for
     ANY base); each group then reconstructs the same full vector from its
     own level-1 all_gather."""
-    if with_checksum:
-        checksum = jnp.abs((s_local * weights).sum())
-    else:
-        checksum = jnp.zeros(())
-    votes = ((norms_local > delta_max) * weights).sum() if delta_max is not None else jnp.zeros(())
-    if lvl1_groups is not None:
-        # hierarchical: per-peer (gs,) table rows (n*gs scalars globally)
-        s_table = s_local[None]
-        norm_table = norms_local[None]
-        w_grp = weights.sum()  # this group's active weight W_a
-        num = jax.lax.psum(
-            w_grp * agg.astype(jnp.float32), peer_axes,
-            axis_index_groups=lvl2_groups,
-        )
-        den = jax.lax.psum(w_grp, peer_axes, axis_index_groups=lvl2_groups)
-        v2 = num / jnp.maximum(den, 1e-30)
-        full = jax.lax.all_gather(
-            v2.astype(g_vec.dtype), peer_axes, tiled=True,
-            axis_index_groups=lvl1_groups,
-        )  # (gs*part,) == padded d, same in every group
+    with jax.named_scope("verify"):
+        if with_checksum:
+            checksum = jnp.abs((s_local * weights).sum())
+        else:
+            checksum = jnp.zeros(())
+        votes = ((norms_local > delta_max) * weights).sum() if delta_max is not None else jnp.zeros(())
+        if lvl1_groups is not None:
+            # hierarchical: per-peer (gs,) table rows (n*gs scalars globally)
+            s_table = s_local[None]
+            norm_table = norms_local[None]
+        else:
+            # broadcast the scalar tables (O(n^2) data total — size-independent)
+            s_table = jax.lax.all_gather(s_local, peer_axes)  # (n_parts, n_peers)
+            norm_table = jax.lax.all_gather(norms_local, peer_axes)
+    with jax.named_scope("gather"):
+        if lvl1_groups is not None:
+            w_grp = weights.sum()  # this group's active weight W_a
+            num = jax.lax.psum(
+                w_grp * agg.astype(jnp.float32), peer_axes,
+                axis_index_groups=lvl2_groups,
+            )
+            den = jax.lax.psum(w_grp, peer_axes, axis_index_groups=lvl2_groups)
+            v2 = num / jnp.maximum(den, 1e-30)
+            full = jax.lax.all_gather(
+                v2.astype(g_vec.dtype), peer_axes, tiled=True,
+                axis_index_groups=lvl1_groups,
+            )  # (gs*part,) == padded d, same in every group
+        else:
+            full = jax.lax.all_gather(
+                agg.astype(g_vec.dtype), peer_axes, tiled=True
+            )  # (n_peers*part,) — gather in transport dtype
         # barrier before the upcast: the gather must ship transport dtype
         full = jax.lax.optimization_barrier(full).astype(jnp.float32)
-    else:
-        # broadcast the scalar tables (O(n^2) data total — size-independent)
-        s_table = jax.lax.all_gather(s_local, peer_axes)  # (n_parts, n_peers)
-        norm_table = jax.lax.all_gather(norms_local, peer_axes)
-        full = jax.lax.all_gather(
-            agg.astype(g_vec.dtype), peer_axes, tiled=True
-        )  # (n_peers*part,) — gather in transport dtype
-        # barrier before the upcast: the gather must ship transport dtype
-        full = jax.lax.optimization_barrier(full).astype(jnp.float32)
-    if pad:
-        full = full[:d]
+        if pad:
+            full = full[:d]
     # checksum/votes are per-partition (expand-dims -> peer-axis out spec);
     # the gathered s/norm tables are the SAME on every peer (the broadcast)
     # so they leave the region as replicated (n_parts, n_peers) arrays —
@@ -692,9 +717,10 @@ def _build_btard_step(
 
         set_manual_axes(peer_axes)  # trace-time: shard() skips peer axes
         try:
-            (loss, metrics), grads = jax.value_and_grad(
-                model.loss_fn, has_aux=True
-            )(params, batch)
+            with jax.named_scope("btard.grads"):
+                (loss, metrics), grads = jax.value_and_grad(
+                    model.loss_fn, has_aux=True
+                )(params, batch)
         finally:
             set_manual_axes(())
         return loss[None], jax.tree.map(lambda g: g[None], grads)
@@ -709,45 +735,50 @@ def _build_btard_step(
     )
 
     # ---- stage 2: butterfly robust all-reduce (fully manual) ---------------
+    # each phase of the step runs under a named scope: the compiled
+    # instructions stay as they are, and each carries its phase in its
+    # ``op_name`` metadata, by which a device trace is read per phase
+    @jax.named_scope("btard.aggregate")
     def butterfly_all(grads, seed, byz_mask, weights, key, *rest):
-        leaves = jax.tree.leaves(grads)
-        # beyond-paper: gradients can travel the butterfly in bf16 — halves
-        # the all_to_all + all_gather volume; CenteredClip still iterates in
-        # f32 (EXPERIMENTS.md §Perf H3)
-        vec = _flatten_local([l[0] for l in leaves], transport_dtype)
-        vec_honest = vec
-        vec = device_attack(vec, byz_mask, peer_axes, attack, key)
-        # per-peer public-seed spot-check residue: every peer's max
-        # deviation between the payload it broadcast and the recompute from
-        # the public batch (vec_honest IS that recompute here) — exact zero
-        # for honest peers. The host membership layer consumes this for
-        # PROBATION slots only (the Sybil gate of core.sybil: a joining
-        # peer is spot-checked every step of its probation window), the
-        # protocol-faithful subset of a per-peer observable.
-        probe = jnp.max(jnp.abs(vec.astype(jnp.float32)
-                                - vec_honest.astype(jnp.float32)))
-        if model_axes:
-            probe = jax.lax.pmax(probe, model_axes)
-        audit_grad = None
-        if spec.verifiable:
-            # gradient-recompute audit (CHOOSETARGET's payload arm): the
-            # shared seed elects one peer; validators recompute its
-            # gradient from the PUBLIC batch — bit-identical here, the
-            # pre-attack vector IS that recompute — and report the max
-            # deviation of the payload it actually sent. Exact zero for
-            # honest peers, so the host ban policy can fire on any nonzero
-            # regardless of the spec's digest linearity.
-            t_peer = jnp.mod(jnp.asarray(seed, jnp.int32), n_peers)
-            audit_grad = jnp.where(
-                jax.lax.axis_index(peer_axes) == t_peer,
-                jnp.max(jnp.abs(vec.astype(jnp.float32)
-                                - vec_honest.astype(jnp.float32))),
-                0.0,
-            )
-        v0_full = None
-        if carry_v0:
-            # previous aggregate, flattened in the SAME leaf order as vec
-            v0_full = _flatten_local(jax.tree.leaves(rest[0]), jnp.float32)
+        with jax.named_scope("flatten"):
+            leaves = jax.tree.leaves(grads)
+            # beyond-paper: gradients can travel the butterfly in bf16 — halves
+            # the all_to_all + all_gather volume; CenteredClip still iterates in
+            # f32 (EXPERIMENTS.md §Perf H3)
+            vec = _flatten_local([l[0] for l in leaves], transport_dtype)
+            vec_honest = vec
+            vec = device_attack(vec, byz_mask, peer_axes, attack, key)
+            # per-peer public-seed spot-check residue: every peer's max
+            # deviation between the payload it broadcast and the recompute from
+            # the public batch (vec_honest IS that recompute here) — exact zero
+            # for honest peers. The host membership layer consumes this for
+            # PROBATION slots only (the Sybil gate of core.sybil: a joining
+            # peer is spot-checked every step of its probation window), the
+            # protocol-faithful subset of a per-peer observable.
+            probe = jnp.max(jnp.abs(vec.astype(jnp.float32)
+                                    - vec_honest.astype(jnp.float32)))
+            if model_axes:
+                probe = jax.lax.pmax(probe, model_axes)
+            audit_grad = None
+            if spec.verifiable:
+                # gradient-recompute audit (CHOOSETARGET's payload arm): the
+                # shared seed elects one peer; validators recompute its
+                # gradient from the PUBLIC batch — bit-identical here, the
+                # pre-attack vector IS that recompute — and report the max
+                # deviation of the payload it actually sent. Exact zero for
+                # honest peers, so the host ban policy can fire on any nonzero
+                # regardless of the spec's digest linearity.
+                t_peer = jnp.mod(jnp.asarray(seed, jnp.int32), n_peers)
+                audit_grad = jnp.where(
+                    jax.lax.axis_index(peer_axes) == t_peer,
+                    jnp.max(jnp.abs(vec.astype(jnp.float32)
+                                    - vec_honest.astype(jnp.float32))),
+                    0.0,
+                )
+            v0_full = None
+            if carry_v0:
+                # previous aggregate, flattened in the SAME leaf order as vec
+                v0_full = _flatten_local(jax.tree.leaves(rest[0]), jnp.float32)
         agg_vec, verif = aggregation_stage(
             vec, peer_axes, n_peers, spec, weights, seed,
             use_pallas=use_pallas, delta_max=delta_max, v0_full=v0_full,
@@ -756,7 +787,8 @@ def _build_btard_step(
             agg_attack_scale=agg_attack, byz_mask=byz_mask,
             audit_grad=audit_grad,
         )
-        agg_leaves = _unflatten_local(agg_vec, [l[0] for l in leaves])
+        with jax.named_scope("gather"):
+            agg_leaves = _unflatten_local(agg_vec, [l[0] for l in leaves])
         agg = jax.tree.unflatten(jax.tree.structure(grads), agg_leaves)
         verif["probe_mismatch"] = probe[None]
         return agg, verif
@@ -799,8 +831,10 @@ def _build_btard_step(
         key = jax.random.fold_in(jax.random.key(seed), step)
         rest = (v_prev,) if carry_v0 else ()
         agg, verif = stage2(grads, seed, byz_mask, weights, key, *rest)
-        updates, opt_state = optimizer.update(agg, opt_state, params, step)
-        params = apply_updates(params, updates)
+        with jax.named_scope("btard.optimizer"):
+            updates, opt_state = optimizer.update(
+                agg, opt_state, params, step)
+            params = apply_updates(params, updates)
         metrics = {
             "loss": loss.mean(),
             "checksum_max": verif["checksum"].max(),
@@ -994,10 +1028,12 @@ def make_btard_scan_train_step(
             def batch_for(xs):
                 # the in-scan data phase: public-seed batch for this round,
                 # generated on device (replicated — see replicated_batch)
-                batch = pipeline.device_batch(xs[-2], extras=extras)
-                return jax.tree.map(
-                    jax.lax.with_sharding_constraint, batch, replicated_batch
-                )
+                with jax.named_scope("btard.data"):
+                    batch = pipeline.device_batch(xs[-2], extras=extras)
+                    return jax.tree.map(
+                        jax.lax.with_sharding_constraint, batch,
+                        replicated_batch,
+                    )
 
             (params, opt_state, v_last), (metrics, verif) = jax.lax.scan(
                 body_of(batch_for, byz_mask, weights),

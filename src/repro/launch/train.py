@@ -191,6 +191,11 @@ def main(argv=None):
                          "--resume to verify recovery)")
     ap.add_argument("--checkpoint", default="")
     ap.add_argument("--log-every", type=int, default=1)
+    ap.add_argument("--trace-dir", default="", metavar="DIR",
+                    help="write a profiler trace of the second and third "
+                         "chunk (compiled by then) under DIR: the device's "
+                         "ops by phase scope and the btard.host.* spans; "
+                         "without --scan-steps a chunk is one step")
     args = ap.parse_args(argv)
 
     if args.host_devices:
@@ -333,7 +338,8 @@ def main(argv=None):
           f"aggregator={agg_spec.canonical()} "
           f"scan={n_scan or '-'} "
           f"data={'device' if device_data else 'host'}")
-    t0 = time.time()
+    clock = ChunkClock(args.trace_dir)
+    span = jax.profiler.TraceAnnotation
     final_loss = float("nan")
     if args.defense == "btard" and n_scan:
         v_prev = jax.tree.map(jnp.zeros_like, params)
@@ -379,6 +385,7 @@ def main(argv=None):
                 **flat_cost,
             )
         for chunk in range(start_step, args.steps, n_scan):
+            clock.begin()
             idxs = list(range(chunk, min(chunk + n_scan, args.steps)))
             # membership events fire at the chunk boundary: every join/leave
             # scheduled inside this chunk's window toggles its slot before
@@ -392,24 +399,27 @@ def main(argv=None):
             steps_arr = jnp.asarray(idxs, jnp.int32)
             seeds = jnp.asarray([s * 7919 + 13 for s in idxs], jnp.int32)
             if device_data:
-                params, opt_state, metrics, verif, v_prev = step_fn(
-                    params, opt_state, steps_arr, seeds, byz_mask, weights,
-                    v_prev,
-                )
+                with span("btard.host.dispatch"):
+                    params, opt_state, metrics, verif, v_prev = step_fn(
+                        params, opt_state, steps_arr, seeds, byz_mask,
+                        weights, v_prev,
+                    )
             else:
                 batches = jax.tree.map(
                     lambda *ls: jnp.stack(ls),
                     *[pipe.batch(s, extras=extras) for s in idxs],
                 )
-                params, opt_state, metrics, verif, v_prev = step_fn(
-                    params, opt_state, batches, steps_arr, seeds, byz_mask,
-                    weights, v_prev,
-                )
+                with span("btard.host.dispatch"):
+                    params, opt_state, metrics, verif, v_prev = step_fn(
+                        params, opt_state, batches, steps_arr, seeds,
+                        byz_mask, weights, v_prev,
+                    )
             # probation spot-checks: each scanned round reported every
             # peer's deviation from its public-seed recompute; feed the
             # probation slots' rows to the gate (ban on any mismatch,
             # promote after a clean window)
-            probes = np.asarray(verif["probe_mismatch"], np.float64)
+            with span("btard.host.fetch"):
+                probes = np.asarray(verif["probe_mismatch"], np.float64)
             if probes.ndim == 1:
                 probes = probes[None]
             for i, s in enumerate(idxs):
@@ -430,22 +440,27 @@ def main(argv=None):
                       flush=True)
             if state_path:
                 next_step = idxs[-1] + 1
-                save_checkpoint(
-                    state_path,
-                    {"params": params, "opt": opt_state, "v_prev": v_prev},
-                    step=next_step,
-                    meta={"arch": args.arch,
-                          "aggregator": agg_spec.canonical()},
-                )
-                save_checkpoint(mem_path, mem.to_tree(), step=next_step)
+                with span("btard.host.checkpoint"):
+                    save_checkpoint(
+                        state_path,
+                        {"params": params, "opt": opt_state,
+                         "v_prev": v_prev},
+                        step=next_step,
+                        meta={"arch": args.arch,
+                              "aggregator": agg_spec.canonical()},
+                    )
+                    save_checkpoint(mem_path, mem.to_tree(), step=next_step)
                 if args.halt_at is not None and next_step >= args.halt_at:
+                    clock.close()
                     print(f"halt requested at step {args.halt_at}: "
                           f"checkpointed step {next_step}, exiting "
                           "(resume with --resume)", flush=True)
                     return _print_summary(json, mem, byz, final_loss,
                                           next_step)
+            clock.end(len(idxs), params)
     else:
         for step in range(args.steps):
+            clock.begin()
             mem.apply_events(step)
             weights = jnp.asarray(mem.weights())
             batch = pipe.batch(step, extras=extras)
@@ -476,14 +491,60 @@ def main(argv=None):
             if step % args.log_every == 0:
                 print(f"step {step:4d} loss={final_loss:.4f}{extra}",
                       flush=True)
-    dt = time.time() - t0
-    print(f"done: {args.steps} steps in {dt:.1f}s ({dt/args.steps:.2f}s/step)")
+            clock.end(1, params)
+    clock.close()
+    print(clock.done())
     summary = _print_summary(json, mem, byz, final_loss, args.steps)
     if args.checkpoint:
         save_checkpoint(args.checkpoint, {"params": params, "opt": opt_state},
                         step=args.steps, meta={"arch": args.arch})
         print("checkpoint saved:", args.checkpoint)
     return summary
+
+
+class ChunkClock:
+    """Wall time per chunk: the first chunk (which compiles) apart from the
+    steady chunks after it. With ``trace_dir`` set, a profiler trace of the
+    second and third chunk is written there."""
+
+    def __init__(self, trace_dir=""):
+        self.trace_dir = trace_dir
+        self.tracing = False
+        self.t0 = time.time()
+        self.ends = []  # (wall time at the chunk's end, steps in it)
+
+    def begin(self):
+        if self.trace_dir and len(self.ends) == 1:
+            import jax
+
+            jax.profiler.start_trace(self.trace_dir)
+            self.tracing = True
+
+    def end(self, n_steps, state):
+        self.ends.append((time.time(), n_steps))
+        if self.tracing and len(self.ends) == 3:
+            self.close(state)
+
+    def close(self, state=None):
+        if self.tracing:
+            import jax
+
+            jax.block_until_ready(state)
+            jax.profiler.stop_trace()
+            self.tracing = False
+
+    def done(self) -> str:
+        if not self.ends:
+            return "done: 0 steps"
+        (t1, n1), (t_last, _) = self.ends[0], self.ends[-1]
+        steps = sum(n for _, n in self.ends)
+        line = (f"done: {steps} steps in {t_last - self.t0:.1f}s; first "
+                f"chunk ({n1} step{'s' * (n1 != 1)}, compile included) "
+                f"{t1 - self.t0:.1f}s")
+        if len(self.ends) > 1:
+            line += (f"; then {(t_last - t1) / (steps - n1):.3f}s/step over "
+                     f"{len(self.ends) - 1} chunks")
+        return line
 
 
 def _print_summary(json, mem, byz, final_loss, steps_done):

@@ -200,6 +200,7 @@ def _project_kv(p, cfg, x, wk="wk", wv="wv"):
     return k, v
 
 
+@jax.named_scope("model.attention")
 def gqa_apply(p, cfg, spec, x, *, pos, memory=None, cache=None, mode="train"):
     """Causal self-attention part of a GQA block.
 
@@ -246,6 +247,7 @@ def gqa_apply(p, cfg, spec, x, *, pos, memory=None, cache=None, mode="train"):
     return y, new_cache
 
 
+@jax.named_scope("model.attention")
 def cross_attn_apply(p, cfg, spec, x, *, memory=None, cache=None, mode="train"):
     """Cross-attention over encoder memory. Returns (y, new_cache_entries)."""
     B, S, _ = x.shape
@@ -345,6 +347,7 @@ def _mla_compress(p, cfg, x, pos, decode):
     return c_kv, k_rope
 
 
+@jax.named_scope("model.attention")
 def mla_apply(p, cfg, spec, x, *, pos, memory=None, cache=None, mode="train"):
     B, S, _ = x.shape
     H = cfg.n_heads

@@ -75,6 +75,7 @@ def act_fn(cfg, x):
     return jax.nn.silu(x)
 
 
+@jax.named_scope("model.mlp")
 def apply_mlp(p, cfg, x):
     h = jnp.einsum("...d,df->...f", x, p["wi"])
     if "wg" in p:
@@ -133,6 +134,7 @@ def embed_init(key, cfg):
     return p
 
 
+@jax.named_scope("model.embed")
 def embed_tokens(p, cfg, tokens, pos=None):
     x = jnp.take(p["embed"], tokens, axis=0)
     if cfg.name.startswith("gemma") or cfg.name.startswith("recurrentgemma"):

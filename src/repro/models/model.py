@@ -108,10 +108,12 @@ class Model:
 
         @jax.checkpoint
         def chunk_loss(emb_params, x_sl, tgt_sl):
-            logits = logits_out(emb_params, cfg, x_sl)
-            lse = jax.nn.logsumexp(logits, axis=-1)
-            tgt = jnp.take_along_axis(logits, tgt_sl[..., None], axis=-1)[..., 0]
-            return (lse - tgt).sum()
+            with jax.named_scope("model.head"):
+                logits = logits_out(emb_params, cfg, x_sl)
+                lse = jax.nn.logsumexp(logits, axis=-1)
+                tgt = jnp.take_along_axis(
+                    logits, tgt_sl[..., None], axis=-1)[..., 0]
+                return (lse - tgt).sum()
 
         emb_params = {k: params[k] for k in ("embed", "lm_head") if k in params}
         total = jnp.zeros((), jnp.float32)

@@ -45,6 +45,7 @@ def capacity(cfg, n_tokens):
     return max(8, -(-c // 8) * 8)  # round up to 8
 
 
+@jax.named_scope("model.mlp")
 def moe_apply(p, cfg, x):
     """x: (B, S, d) -> (y, aux_loss).
 

@@ -20,3 +20,28 @@ def _reset_sharding_state():
     set_mesh(None)
     set_manual_axes(())
     set_seq_parallel(False)
+
+
+@pytest.fixture(scope="module")
+def described_chip():
+    """One chip of a described v5e:2x2, to compile for with no TPU attached,
+    with the persistent compile cache off (a program compiled for a
+    described chip cannot be read back). Described inside a module-scoped
+    fixture, never while a module is imported: only one process at a time
+    may load the TPU library, and pytest-xdist workers must all collect the
+    same tests."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield topo.devices[0]
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()

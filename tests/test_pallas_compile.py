@@ -24,26 +24,8 @@ from repro.kernels import centered_clip as _k
 
 
 @pytest.fixture(scope="module")
-def one_chip():
-    """One chip of a described v5e:2x2, with the persistent compile cache
-    off (a program compiled for a described chip cannot be read back)."""
-    import os
-
-    from jax.experimental import topologies
-    from jax.experimental.compilation_cache import compilation_cache
-
-    os.environ.setdefault("TPU_LOG_DIR", "disabled")
-    try:
-        topo = topologies.get_topology_desc(
-            platform="tpu", topology_name="v5e:2x2")
-    except Exception as e:  # no TPU compiler here
-        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    was = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
-    jax.config.update("jax_enable_compilation_cache", was)
-    compilation_cache.reset_cache()
+def one_chip(described_chip):
+    return SingleDeviceSharding(described_chip)
 
 
 def _validate(one_chip, fn, *args):
