@@ -6,15 +6,41 @@ never materializes repeated KV. Softmax runs in f32.
 Long sequences use a *python-unrolled* blocked online-softmax (no lax.scan)
 so the dry-run roofline sees the true FLOP/byte counts (cost_analysis counts
 a scan body only once — see DESIGN.md).
+
+On a TPU, GQA's causal self-attention (train and prefill) runs as the fused
+Pallas flash-attention kernel of the installed jax
+(``jax.experimental.pallas.ops.tpu.splash_attention``: the forward
+``splash_mha_fwd_residuals`` (``_no_residuals`` where nothing is
+differentiated) and the fused backward
+``splash_mha_dkv_no_residuals``, which gives dq, dk and dv): softmax online
+in VMEM, block by block, so neither pass writes an S x S tensor to HBM.
+``flash_blocks`` decides from what the trace can observe, and ``gqa_apply``
+then projects straight into the kernel's (B, H, S, D) layout. The kernel
+runs where
+
+- the program is traced for a TPU (the registered mesh's devices, else the
+  default backend) and no automatically partitioned mesh axis of size > 1
+  would have to split it (XLA cannot partition a Mosaic call; the peer axes
+  of the BTARD step's shard_map are manual);
+- q, k and v share one head dim, at most 128 or a multiple of 128;
+- S and T are multiples of 128, T <= ``DENSE_MAX_KV``, and any sliding
+  window covers all of T.
+
+Everything else takes the jnp paths below, unchanged: the CPU, MLA (k's
+head dim differs from v's), shorter windows, longer sequences, cross
+attention and decode.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental.pallas.ops.tpu import splash_attention as _splash
+from jax.sharding import PartitionSpec as P
 
 from repro.models.layers import apply_rope, cdtype, dense_init, rms_head_norm
-from repro.sharding import shard
+from repro.sharding import get_mesh, shard
+from repro.sharding.specs import get_manual_axes
 
 NEG_INF = -2.0e38
 DENSE_MAX_KV = 8192  # use dense path when kv_len <= this
@@ -137,6 +163,78 @@ def causal_attention(q, k, v, window=0):
 
 
 # ---------------------------------------------------------------------------
+# Fused flash-attention kernel (TPU)
+# ---------------------------------------------------------------------------
+FLASH_TILE = 128  # the kernel's lane tile: every block is a multiple of it
+
+
+def _kernel_allowed() -> bool:
+    """Whether the program being traced may hold a Mosaic kernel: it is
+    traced for a TPU (the registered mesh's devices, else the default
+    backend), and no automatically partitioned mesh axis of size > 1 would
+    have to split the kernel."""
+    mesh = get_mesh()
+    if mesh is None:
+        return jax.default_backend() == "tpu"
+    if mesh.devices.flat[0].platform != "tpu":
+        return False
+    manual = get_manual_axes()
+    return all(n == 1 for a, n in mesh.shape.items() if a not in manual)
+
+
+def _block(n: int, cap: int) -> int:
+    """The largest multiple of ``FLASH_TILE`` up to ``cap`` that divides n."""
+    return max(b for b in range(FLASH_TILE, min(n, cap) + 1, FLASH_TILE)
+               if n % b == 0)
+
+
+def flash_blocks(seq, kv_len, head_dim, v_head_dim, window=0):
+    """The kernel's block sizes for causal self-attention of these shapes,
+    or None where the jnp path runs (module docstring). A block spans up to
+    512 rows, the whole of ALBERT's sequence, so a grid step holds one
+    (head, example)'s full 512 x 512 tile; the backward is the one fused
+    kernel that gives dq, dk and dv."""
+    if not _kernel_allowed() or head_dim != v_head_dim:
+        return None
+    if head_dim > FLASH_TILE and head_dim % FLASH_TILE:
+        return None
+    if seq % FLASH_TILE or kv_len % FLASH_TILE or kv_len > DENSE_MAX_KV:
+        return None
+    if window and window < kv_len:
+        return None
+    bq, bk = _block(seq, 512), _block(kv_len, 512)
+    return _splash.BlockSizes(
+        block_q=bq, block_kv=bk, block_kv_compute=bk,
+        block_q_dkv=bq, block_kv_dkv=bk, block_kv_dkv_compute=bk,
+        use_fused_bwd_kernel=True,
+    )
+
+
+def flash_causal_attention(q, k, v, blocks):
+    """Causal self-attention by the fused kernel. q, k, v: (B, H, S, D) with
+    equal head counts -> (B, H, S, D). The kernel runs (B * H) heads of one
+    sequence each, so the batch folds into the heads with no layout change.
+    It applies no scale, so q comes in scaled by 1/sqrt(D) in its own dtype:
+    exact where D is a power of 4 (64 here), else one rounding of q.
+
+    Inside a manual region (the BTARD step's per-peer shard_map) a Mosaic
+    call must see every mesh axis manual, so the kernel is wrapped in a
+    shard_map over the axes still automatic, all of size 1 here."""
+    B, H, S, D = q.shape
+    mask = _splash.MultiHeadMask([_splash.CausalMask((S, k.shape[2]))] * (B * H))
+    kernel = _splash.make_splash_mha(
+        mask, block_sizes=blocks, head_shards=1, q_seq_shards=1)
+    mesh = jax.sharding.get_abstract_mesh()
+    auto = set(mesh.axis_names) - set(mesh.manual_axes)
+    if mesh.manual_axes and auto:
+        kernel = jax.shard_map(kernel, in_specs=(P(),) * 3, out_specs=P(),
+                               axis_names=auto, check_vma=False)
+    q = (q * (1.0 / np.sqrt(D))).astype(q.dtype)
+    out = kernel(*(t.reshape(B * H, *t.shape[2:]) for t in (q, k, v)))
+    return out.reshape(B, H, S, D)
+
+
+# ---------------------------------------------------------------------------
 # GQA block (full / local / cross)
 # ---------------------------------------------------------------------------
 def gqa_init(key, cfg, spec):
@@ -211,6 +309,10 @@ def gqa_apply(p, cfg, spec, x, *, pos, memory=None, cache=None, mode="train"):
     B, S, _ = x.shape
     H, Kv, D = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     window = cfg.window if spec.mixer == "attn_local" else 0
+    if mode != "decode":
+        blocks = flash_blocks(S, S, D, D, window)
+        if blocks is not None:
+            return _gqa_flash(p, cfg, x, pos, blocks, cache)
     new_cache = {} if cache is not None else None
 
     q = _project_q(p, cfg, x)
@@ -244,6 +346,43 @@ def gqa_apply(p, cfg, spec, x, *, pos, memory=None, cache=None, mode="train"):
 
     y = shard(attn.reshape(B, S, H * D), "batch", None, "model")
     y = jnp.einsum("bse,ed->bsd", y, p["wo"])
+    return y, new_cache
+
+
+def _project_heads(x, w, bias, n, D):
+    """x (B, S, d) through w (d, n*D) straight into the kernel's layout
+    (B, n, S, D)."""
+    y = jnp.einsum("bsd,dhk->bhsk", x, w.reshape(w.shape[0], n, D))
+    if bias is not None:
+        y = y + bias.reshape(n, 1, D)
+    return y
+
+
+def _gqa_flash(p, cfg, x, pos, blocks, cache):
+    """``gqa_apply``'s train / prefill self-attention through the fused
+    kernel: the projections write (B, H, S, D) and the output projection
+    reads it, so no layout change stands between them and the kernel. No
+    sharding hint is placed: the kernel runs only where no automatic mesh
+    axis has more than one device."""
+    H, Kv, D = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    q = _project_heads(x, p["wq"], p.get("wq_bias"), H, D)
+    k = _project_heads(x, p["wk"], p.get("wk_bias"), Kv, D)
+    v = _project_heads(x, p["wv"], p.get("wv_bias"), Kv, D)
+    if "q_norm" in p:
+        q = rms_head_norm(p["q_norm"], q, cfg.norm_eps)
+        k = rms_head_norm(p["k_norm"], k, cfg.norm_eps)
+    # rope over (..., S, 1, D): the sequence axis stands before a unit head
+    q = apply_rope(q[:, :, :, None], pos[None, :], cfg)[:, :, :, 0]
+    k = apply_rope(k[:, :, :, None], pos[None, :], cfg)[:, :, :, 0]
+    new_cache = None
+    if cache is not None:  # prefill: the cache keeps its (B, T, K, D) layout
+        new_cache = {"k": _cache_prefill(cache["k"], k.swapaxes(1, 2)),
+                     "v": _cache_prefill(cache["v"], v.swapaxes(1, 2))}
+    if Kv < H:  # the same head-shardable expand as the jnp path
+        k = jnp.repeat(k, H // Kv, axis=1)
+        v = jnp.repeat(v, H // Kv, axis=1)
+    attn = flash_causal_attention(q, k, v, blocks)
+    y = jnp.einsum("bhsk,hkd->bsd", attn, p["wo"].reshape(H, D, -1))
     return y, new_cache
 
 

@@ -14,6 +14,8 @@ The topology is described inside a module-scoped fixture, never while a
 module is imported: only one process at a time may load the TPU library,
 and pytest-xdist workers must all collect the same tests.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -329,3 +331,80 @@ def test_adaptive_driver_lowers_natively(one_chip, warm):
         )
 
     _validate(one_chip, fn, _stack(12, (PARTS, N, D)))
+
+
+# ---------------------------------------------------------------------------
+# Fused flash attention inside the model's gradient step
+# ---------------------------------------------------------------------------
+# the splash kernels' names: the forward (it saves its residuals in the
+# primal pass and in the rematerialisation alike) and the fused backward
+FLASH_KERNELS = ("splash_mha_fwd", "splash_mha_dkv")
+
+
+def _one_chip_mesh(chip):
+    from jax.sharding import AxisType, Mesh
+
+    return Mesh(np.array([[chip]]), ("data", "model"),
+                axis_types=(AxisType.Auto,) * 2)
+
+
+def _grads_program(chip, arch, batch, seq):
+    """The model's value-and-grad step compiled for the described chip, with
+    a one-chip mesh of it registered (the step decides its attention path by
+    the mesh's platform). Returns the compiled program's text."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from repro.models import get_model
+    from repro.sharding import set_mesh
+
+    mesh = _one_chip_mesh(chip)
+    set_mesh(mesh)
+    rep = NamedSharding(mesh, P())
+    m = get_model(arch, reduced=True)
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=rep),
+        m.abstract_params())
+    tokens = jax.ShapeDtypeStruct((batch, seq + 1), jnp.int32, sharding=rep)
+    step = jax.jit(jax.value_and_grad(m.loss_fn, has_aux=True))
+    return step.lower(params, {"tokens": tokens}).compile().as_text()
+
+
+def _kernel_lines(text):
+    """The Mosaic calls of a compiled program's text, one instruction each
+    (a kernel's JSON metadata may carry the instruction over lines)."""
+    instructions = re.split(r"\n(?=\s*(?:ROOT )?%\S+ = )", text)
+    return [i for i in instructions
+            if 'custom_call_target="tpu_custom_call"' in i]
+
+
+def test_albert_btard_step_runs_flash_attention_natively(described_chip):
+    """albert-large's BTARD step at S = 512 (the benchmark cell's sequence,
+    8 rows), its gradients inside the per-peer shard_map: the forward, its
+    rematerialisation and the fused backward kernel are Mosaic calls under
+    ``model.attention``, and no (B, H, S, S) tensor is left in the program."""
+    from repro.configs import InputShape
+    from repro.launch.steps import make_btard_train_step
+    from repro.models import get_model
+    from repro.optim import sgd
+
+    B, H, S = 8, 16, 512
+    step, abstract = make_btard_train_step(
+        get_model("albert-large"), sgd(0.01), _one_chip_mesh(described_chip),
+        InputShape("albert", S, B, "train"))
+    text = step.lower(*abstract).compile().as_text()
+    kernels = _kernel_lines(text)
+    names = [l.split("=")[0].strip().lstrip("%") for l in kernels]
+    for kernel in FLASH_KERNELS:
+        assert any(n.startswith(kernel) for n in names), (kernel, names)
+    assert sum(n.startswith("splash_mha_fwd") for n in names) == 2  # fwd, remat
+    assert sum(n.startswith("splash_mha_dkv") for n in names) == 1
+    assert all("model.attention" in l for l in kernels)
+    assert f"[{B},{H},{S},{S}]" not in text
+
+
+def test_mla_layer_keeps_the_jnp_attention(described_chip):
+    """MLA's k head dim (nope + rope) differs from v's: its gradient step,
+    compiled for the same chip, holds none of the attention kernels."""
+    text = _grads_program(described_chip, "deepseek-v2-lite-16b", 2, 512)
+    names = [l.split("=")[0].strip().lstrip("%") for l in _kernel_lines(text)]
+    assert not [n for n in names if n.startswith(FLASH_KERNELS)], names
