@@ -55,6 +55,7 @@ def _gated_norm(p, cfg, y, z):
     return (yn * p["gate_norm"]).astype(y.dtype)
 
 
+@jax.named_scope("model.ssd")
 def ssd_chunked(x, a_log, dt, Bm, Cm, chunk, init_state=None):
     """Chunked SSD.
 
@@ -132,7 +133,7 @@ def ssd_chunked(x, a_log, dt, Bm, Cm, chunk, init_state=None):
     return y, S_all[:, -1]
 
 
-@jax.named_scope("model.mlp")
+@jax.named_scope("model.ssm")
 def ssm_apply(p, cfg, spec, x, *, pos=None, memory=None, cache=None, mode="train"):
     B, S, _ = x.shape
     d_in, N, H, P = cfg.ssm_inner, cfg.ssm_state, cfg.ssm_heads, cfg.ssm_head_dim

@@ -24,6 +24,8 @@ from repro.optim import sgd
 
 TINY = dict(d_model=64, n_heads=4, n_kv_heads=4, head_dim=16, d_ff=128,
             vocab_size=256, n_repeats=2, max_position=64, tie_embeddings=True)
+MAMBA_TINY = dict(d_model=64, vocab_size=256, n_repeats=2, ssm_state=16,
+                  ssm_head_dim=16, ssm_chunk=8, tie_embeddings=True)
 SCOPES = ("btard.data", "btard.grads", "btard.aggregate/flatten",
           "btard.aggregate/exchange", "btard.aggregate/clip", "btard.aggregate/verify",
           "btard.aggregate/gather", "btard.optimizer", "model.embed",
@@ -44,8 +46,8 @@ SCOPED = re.compile(r"(?:^|[/(])(?:btard|model)\.")
 OP_NAME = re.compile(r'op_name="((?:[^"\\]|\\.)*)"')
 
 
-def _step(device, use_pallas=False):
-    cfg = dataclasses.replace(get_config("albert-large"), **TINY)
+def _step(device, use_pallas=False, arch="albert-large", tiny=TINY):
+    cfg = dataclasses.replace(get_config(arch), **tiny)
     model = Model(cfg)
     devs = [[device]] if device is not None else [[jax.devices()[0]]]
     mesh = Mesh(devs, ("data", "model"), axis_types=(AxisType.Auto,) * 2)
@@ -134,3 +136,29 @@ def test_pallas_kernels_carry_their_names(described_chip):
     assert "tpu_custom_call" not in interp
     assert re.search(r'op_name="[^"]*btard\.aggregate/clip/[^"]*cc_fused/',
                      interp)
+
+
+@pytest.fixture(scope="module")
+def compiled_mamba(described_chip):
+    return _step(described_chip, arch="mamba2-2.7b",
+                 tiny=MAMBA_TINY).compile().as_text()
+
+
+@pytest.mark.parametrize("scope", ["model.ssm", "model.ssd"])
+def test_mamba_mixer_phases_in_the_compiled_step(compiled_mamba, scope):
+    """Mamba-2's mixer runs under ``model.ssm``, its chunked dual under a
+    nested ``model.ssd``: forward, backward and rematerialised forward."""
+    names = OP_NAME.findall(compiled_mamba)
+    scoped = [n for n in names if re.search(rf"(?:^|[/(]){re.escape(scope)}[/)]", n)]
+    assert any("transpose(" in n for n in scoped)
+    assert any("rematted_computation" in n for n in scoped)
+    assert any("transpose(" not in n and "rematted_computation" not in n for n in scoped)
+    if scope == "model.ssd":
+        assert all(re.search(r"model\.ssm\)?/.*model\.ssd", n) for n in scoped)
+
+
+def test_mamba_step_has_no_mlp_phase(compiled_mamba):
+    names = OP_NAME.findall(compiled_mamba)
+    assert not any("model.mlp" in n for n in names)
+    assert any("model.embed" in n for n in names)
+    assert any("model.head" in n for n in names)
